@@ -24,9 +24,13 @@ from repro.kernels.dma_profile import (
 
 
 def main() -> None:
+    import jax
+
     smoke = is_smoke()
-    shape = dict(b=4, page=8, pps=2, meta_max=8) if smoke else \
-        dict(b=8, page=16, pps=4, meta_max=16)
+    # one lane-aligned shape everywhere: the compiled kernel on a TPU, the
+    # interpreter elsewhere — never the interpreter timed on a chip
+    shape = dict(b=8, page=128, pps=4, meta_max=128,
+                 interpret=jax.default_backend() != "tpu")
     iters = 3 if smoke else 8
     warmup = 1 if smoke else 2
 
@@ -51,7 +55,7 @@ def main() -> None:
            compute_us=decomp["compute_s"] * 1e6,
            fused_us=decomp["fused_s"] * 1e6, **shape)
 
-    depth = auto_buffer_depth(profiles=profs)
+    depth = auto_buffer_depth(interpret=shape["interpret"], profiles=profs)
     csv("dma_overlap_selected", 0.0,
         f"auto_depth={depth} "
         f"candidates={'/'.join(str(d) for d in DEFAULT_DEPTHS)}")

@@ -14,63 +14,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
-# --- jax version compat ------------------------------------------------------
-# ``AxisType`` / ``make_mesh(axis_types=...)`` only exist on newer jax; on
-# jax 0.4.x every mesh axis is implicitly Auto, so the fallbacks below are
-# semantically identical for this codebase (which only ever uses Auto).
-try:
-    from jax.sharding import AxisType  # jax >= 0.5
-
-    HAS_AXIS_TYPE = True
-except ImportError:  # jax 0.4.x
-    HAS_AXIS_TYPE = False
-
-    class AxisType:  # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
-              *, axis_types=None, devices=None) -> Mesh:
-    """``jax.make_mesh`` that tolerates jax versions without ``axis_types``."""
-    kw = {} if devices is None else {"devices": devices}
-    if HAS_AXIS_TYPE:
-        if axis_types is None:
-            axis_types = (AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=axis_types, **kw)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-
-
-def abstract_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.sharding.AbstractMesh`` across the 0.4.x/0.5.x signature change
-    ((sizes, names) vs a single ((name, size), ...) tuple)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(
-            tuple(zip(axis_names, axis_shapes)))
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across versions: on jax 0.4.x it lives in
-    ``jax.experimental.shard_map`` and the replication-check kwarg is
-    ``check_rep`` rather than ``check_vma``."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma)
-        except TypeError:
-            # transition releases expose top-level shard_map but still
-            # spell the replication check ``check_rep``
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_vma)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_vma)
-
 # logical axis -> ordered tuple of physical mesh axes it may shard over.
 DEFAULT_RULES: Dict[str, Tuple[str, ...]] = {
     # activations

@@ -55,7 +55,10 @@ class DevicePool(TokenPool):
 
     def __init__(self, alloc: AnchorPool):
         super().__init__(alloc)
-        self._dev = None                      # jax.Array [P+1, page] int32
+        # jax.Array [P+1, 1, page] int32: the kernels' row view of the
+        # [P+1, page] pool (one page per tiled row), so device rounds move
+        # whole pages with no relayout of the pool
+        self._dev = None
         rows = self._flat.shape[0]
         self._host_dirty = np.zeros((rows,), bool)
         self._dev_dirty = np.zeros((rows,), bool)
@@ -69,6 +72,12 @@ class DevicePool(TokenPool):
     def resident(self) -> bool:
         """True once the device copy exists (first device round)."""
         return self._dev is not None
+
+    def block_until_ready(self) -> None:
+        """Wait until every dispatched device round has finished writing
+        the resident pool (timing hook; no-op before residency)."""
+        if self._dev is not None:
+            self._dev.block_until_ready()
 
     def dirty_rows(self) -> np.ndarray:
         """Rows whose truth currently lives on the device (host mirror
@@ -86,7 +95,7 @@ class DevicePool(TokenPool):
         flat = self._flat
         oob = ((flat < I32_MIN) | (flat > I32_MAX)).any(axis=1)
         self._host_dirty |= oob
-        self._dev = jnp.asarray(flat.astype(np.int32))
+        self._dev = jnp.asarray(flat.astype(np.int32)[:, None, :])
         self.xfer["resident_init_tokens"] += flat.size
 
     def _upload_rows(self, rows: np.ndarray) -> None:
@@ -99,7 +108,7 @@ class DevicePool(TokenPool):
         vals = self._flat[sel]
         if vals.size and (vals.min() < I32_MIN or vals.max() > I32_MAX):
             raise DeviceRangeError("host-truth rows exceed int32")
-        self._dev = self._dev.at[sel].set(vals.astype(np.int32))
+        self._dev = self._dev.at[sel].set(vals.astype(np.int32)[:, None, :])
         self._host_dirty[sel] = False
         self.xfer["h2d_tokens"] += vals.size
 
@@ -109,7 +118,7 @@ class DevicePool(TokenPool):
         sel = rows[self._dev_dirty[rows]]
         if len(sel) == 0:
             return
-        host = np.asarray(self._dev[sel]).astype(np.int64)
+        host = np.asarray(self._dev[sel])[:, 0, :].astype(np.int64)
         self._flat[sel] = host
         self._dev_dirty[sel] = False
         self.xfer["d2h_tokens"] += host.size

@@ -703,28 +703,9 @@ class LibraStack:
                 # [j*page, (j+1)*page) through table slot j — only the
                 # allocator's contiguous layout qualifies
                 return False
-        b = len(items)
-        pps = max(max(len(it.pages) for it in items), 1)
-        meta_max = max(max(it.meta_len for it in items), 1)
-        s = max(it.meta_len + len(it.pages) * page for it in items)
-        s = max(-(-max(s, meta_max) // page) * page, page)
-        stream = np.zeros((b, s), np.int32)
-        meta_len = np.zeros((b,), np.int32)
-        total_len = np.zeros((b,), np.int32)
-        tables = np.full((b, pps), -1, np.int32)
-        ks = np.zeros((b, s), np.int32) if any(
-            it.ks is not None for it in items) else None
-        for i, it in enumerate(items):
-            msg = it.meta_len + it.payload_len
-            stream[i, : it.meta_len] = it.meta
-            stream[i, it.meta_len : msg] = it.payload
-            meta_len[i] = it.meta_len
-            total_len[i] = msg
-            if it.ks is not None:
-                ks[i, it.meta_len : msg] = it.ks
-            for j, pg in enumerate(it.pages):
-                tables[i, j] = self.alloc.flat_pid(pg)
-        txks = self._speculate_tx(items, tx_hints, pps * page)
+        stream, meta_len, total_len, tables, ks, meta_max = \
+            self._round_operands(items)
+        txks = self._speculate_tx(items, tx_hints, tables.shape[1] * page)
         off = lo = hi = live = None
         if policy is not None:
             off, lo, hi = policy.cond_off, policy.cond_lo, policy.cond_hi
@@ -754,9 +735,10 @@ class LibraStack:
         likely destination (``tx_hints``: src fd -> socket) is known gets a
         forward-time cache descriptor on its :class:`_BatchItem`; hw-kTLS
         destinations additionally contribute rows to the returned
-        [B, width] TX-keystream operand (ONE vectorized sweep, exactly the
-        forward_batch schedule) so the fused gather emits ciphertext and
-        the metadata span is stashed for seal_meta at forward time. Wrong
+        [B, 1, width] TX-keystream operand (the kernels' row view; ONE
+        vectorized sweep, exactly the forward_batch schedule) so the fused
+        gather emits ciphertext and the metadata span is stashed for
+        seal_meta at forward time. Wrong
         guesses cost nothing — forward_batch validates the descriptor and
         falls back to its own gather."""
         txks = None
@@ -782,15 +764,53 @@ class LibraStack:
                 [crypto.tx_key for _, crypto, _, _ in enc],
                 [seq for _, _, seq, _ in enc],
                 [imeta + items[i].payload_len for i, _, _, imeta in enc])
-            txks = np.zeros((len(items), width), np.int32)
+            txks = np.zeros((len(items), 1, width), np.int32)
             for (i, crypto, seq, imeta), ksr in zip(enc, kss):
                 it = items[i]
-                txks[i, : it.payload_len] = ksr[imeta:]
+                txks[i, 0, : it.payload_len] = ksr[imeta:]
                 it.fused_tx = {
                     "dst_fd": tx_hints[it.sock.fileno()].fileno(),
                     "crypto": crypto, "plen": it.payload_len, "seq": seq,
                     "meta_ks": ksr[:imeta], "payload": None}
         return txks
+
+    def _round_operands(self, items: List[_BatchItem]):
+        """Flatten a round into the kernels' [B, S] operands, built as
+        their [B, 1, S] row view (free here; a relayout copy per round on a
+        TPU). Row ``i`` holds its metadata in lanes ``[0, meta_len)`` and
+        its payload from lane ``meta_max`` on, where ``meta_max`` is the
+        round's longest metadata prefix rounded up by
+        :func:`~repro.kernels.selective_copy.stream_meta_width` (a lane
+        tile at lane-multiple pages, so payload pages start lane-aligned as
+        the compiled kernels need) and ``S = meta_max + pps * page``. int64
+        host tokens ride the int32 device stream: the callers pre-checked
+        their range. Returns ``(stream, meta_len, total_len, tables,
+        keystream | None, meta_max)``; hw-kTLS rows put their RX keystream
+        on the payload lanes of ``keystream``."""
+        from repro.kernels.selective_copy import stream_meta_width
+
+        page = self.alloc.page_size
+        b = len(items)
+        pps = max(max(len(it.pages) for it in items), 1)
+        meta_max = stream_meta_width(max(it.meta_len for it in items), page)
+        s = meta_max + pps * page
+        stream = np.zeros((b, 1, s), np.int32)
+        meta_len = np.zeros((b,), np.int32)
+        total_len = np.zeros((b,), np.int32)
+        tables = np.full((b, pps), -1, np.int32)
+        ks = np.zeros((b, 1, s), np.int32) if any(
+            it.ks is not None for it in items) else None
+        for i, it in enumerate(items):
+            end = meta_max + it.payload_len
+            stream[i, 0, : it.meta_len] = it.meta
+            stream[i, 0, meta_max:end] = it.payload
+            meta_len[i] = it.meta_len
+            total_len[i] = it.meta_len + it.payload_len
+            if it.ks is not None:
+                ks[i, 0, meta_max:end] = it.ks
+            for j, pg in enumerate(it.pages):
+                tables[i, j] = self.alloc.flat_pid(pg)
+        return stream, meta_len, total_len, tables, ks, meta_max
 
     def _recv_batch_device(self, items: List[_BatchItem], impl: str) -> bool:
         """Flatten the round into one [B, S] batch and run the fused
@@ -800,30 +820,8 @@ class LibraStack:
         their RX keystream as the kernel's ``keystream`` operand, so
         decryption is fused into the payload placement. Returns False when
         the round must bounce to the int64-exact host scatter."""
-        page = self.alloc.page_size
-        b = len(items)
-        pps = max(len(it.pages) for it in items)
-        meta_max = max(max(it.meta_len for it in items), 1)
-        s = max(it.meta_len + len(it.pages) * page for it in items)
-        s = max(-(-max(s, meta_max) // page) * page, page)
-        stream = np.zeros((b, s), np.int32)
-        meta_len = np.zeros((b,), np.int32)
-        total_len = np.zeros((b,), np.int32)
-        tables = np.full((b, pps), -1, np.int32)
-        ks = np.zeros((b, s), np.int32) if any(
-            it.ks is not None for it in items) else None
-        for i, it in enumerate(items):
-            msg = it.meta_len + it.payload_len
-            # int64 host tokens ride the int32 device stream; recv_batch
-            # pre-checked the range (out-of-range rounds fall back to host)
-            stream[i, : it.meta_len] = it.meta
-            stream[i, it.meta_len : msg] = it.payload
-            meta_len[i] = it.meta_len
-            total_len[i] = msg
-            if it.ks is not None:
-                ks[i, it.meta_len : msg] = it.ks
-            for j, pg in enumerate(it.pages):
-                tables[i, j] = self.alloc.flat_pid(pg)
+        stream, meta_len, total_len, tables, ks, meta_max = \
+            self._round_operands(items)
         try:
             self.pool.anchor_batch_device(stream, meta_len, total_len,
                                           tables, meta_max=meta_max,

@@ -73,13 +73,15 @@ def _run(case: Dict, *, meta_max: int, n_buffers: int, interpret: bool):
     return got
 
 
-def profile_fused_depths(*, b: int = 8, page: int = 16, pps: int = 4,
-                         meta_max: int = 16,
+def profile_fused_depths(*, interpret: bool, b: int = 8, page: int = 16,
+                         pps: int = 4, meta_max: int = 16,
                          depths: Sequence[int] = DEFAULT_DEPTHS,
                          iters: int = 5, warmup: int = 2,
-                         interpret: bool = True,
                          seed: int = 0) -> Dict[int, DepthProfile]:
     """Wall-clock the full-operand fused round per candidate depth.
+    ``interpret`` has no default: the caller states whether it times the
+    compiled kernel (a TPU, lane-multiple ``page``/``meta_max``) or the
+    interpreter.
 
     Warmup rounds absorb tracing/compile; the reported figure is the
     best of ``iters`` timed rounds (min is the right statistic for a
@@ -99,9 +101,9 @@ def profile_fused_depths(*, b: int = 8, page: int = 16, pps: int = 4,
     return out
 
 
-def dma_compute_profile(*, b: int = 8, page: int = 16, pps: int = 4,
-                        meta_max: int = 16, iters: int = 5, warmup: int = 2,
-                        n_buffers: int = 2, interpret: bool = True,
+def dma_compute_profile(*, interpret: bool, b: int = 8, page: int = 16,
+                        pps: int = 4, meta_max: int = 16, iters: int = 5,
+                        warmup: int = 2, n_buffers: int = 2,
                         seed: int = 0) -> Dict[str, float]:
     """Decompose the staged round into transfer vs compute legs.
 
@@ -151,11 +153,10 @@ def dma_compute_profile(*, b: int = 8, page: int = 16, pps: int = 4,
             "overlap_ratio": float(np.clip(overlap, 0.0, 1.0))}
 
 
-def auto_buffer_depth(*, b: int = 8, page: int = 16, pps: int = 4,
-                      meta_max: int = 16,
+def auto_buffer_depth(*, interpret: bool, b: int = 8, page: int = 16,
+                      pps: int = 4, meta_max: int = 16,
                       depths: Sequence[int] = DEFAULT_DEPTHS,
-                      iters: int = 3, warmup: int = 1,
-                      interpret: bool = True, seed: int = 0,
+                      iters: int = 3, warmup: int = 1, seed: int = 0,
                       profiles: Optional[Dict[int, DepthProfile]] = None,
                       ) -> int:
     """The staging depth the fused datapath should run with.

@@ -74,47 +74,67 @@ def paged_attention_ref(
     return (acc.reshape(b, hq, hd), m.reshape(b, hq), l.reshape(b, hq))
 
 
-def selective_copy_ref(
-    stream: jax.Array,    # [B, S] int32 token stream
-    meta_len: jax.Array,  # [B] metadata boundary from the parser policy
-    total_len: jax.Array, # [B] message length in the stream
-    pool: jax.Array,      # [P, page] anchored payload pages
-    tables: jax.Array,    # [B, pps] destination page ids (-1 unused)
-    *,
-    meta_max: int,
-) -> Tuple[jax.Array, jax.Array]:
-    """RX-Prog data plane: compact metadata into [B, meta_max] (selective
-    copy) and scatter the payload into anchored pages (single placement).
-    Returns (meta_buf, new_pool)."""
+def _flat(x):
+    """[B, W] view of a [B, W] operand or of its row view [B, 1, W]."""
+    return x.reshape(x.shape[0], x.shape[-1])
+
+
+def _anchor_ref(stream, meta_len, total_len, pool, tables, payload, *,
+                meta_max):
+    """Shared body of the selective-copy oracles: ``stream`` supplies the
+    metadata window, ``payload`` (same [B, S] layout; plaintext) the
+    tokens that land in the pages."""
+    stream, payload = _flat(stream), _flat(payload)
     b, s = stream.shape
-    p_, page = pool.shape
+    page = pool.shape[-1]
+    flat_pool = pool.reshape(-1, page)
+    p_ = flat_pool.shape[0]
     pps = tables.shape[1]
     idx = jnp.arange(meta_max)
     meta_buf = jnp.where(idx[None, :] < meta_len[:, None],
                          jnp.take_along_axis(
                              stream, jnp.minimum(idx[None, :], s - 1), axis=1),
                          0)
-    # payload token t (global stream position meta_len + t) -> page t//page
+    # payload token t (stream lane meta_max + t) -> page t // page
     t = jnp.arange(s)
-    rel = t[None, :] - meta_len[:, None]                  # payload-relative pos
-    valid = (rel >= 0) & (t[None, :] < total_len[:, None])
+    rel = t[None, :] - meta_max                            # payload-relative
+    valid = (rel >= 0) & (rel < (total_len - meta_len)[:, None])
     pg = jnp.clip(rel // page, 0, pps - 1)
     dest_page = jnp.take_along_axis(tables, pg, axis=1)   # [B, S]
     dest_off = rel % page
     flat_dest = jnp.where(valid & (dest_page >= 0),
                           dest_page * page + dest_off, p_ * page)
-    new_pool = pool.reshape(-1).at[flat_dest.reshape(-1)].set(
-        stream.reshape(-1).astype(pool.dtype), mode="drop").reshape(p_, page)
-    return meta_buf, new_pool
+    new_pool = flat_pool.reshape(-1).at[flat_dest.reshape(-1)].set(
+        payload.reshape(-1).astype(pool.dtype), mode="drop")
+    return meta_buf, new_pool.reshape(pool.shape)
+
+
+def selective_copy_ref(
+    stream: jax.Array,    # [B, S] int32 token stream (or [B, 1, S])
+    meta_len: jax.Array,  # [B] metadata boundary from the parser policy
+    total_len: jax.Array, # [B] message length (metadata + payload)
+    pool: jax.Array,      # [P, page] anchored payload pages (or [P, 1, page])
+    tables: jax.Array,    # [B, pps] destination page ids (-1 unused)
+    *,
+    meta_max: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """RX-Prog data plane: compact metadata into [B, meta_max] (selective
+    copy) and scatter the payload into anchored pages (single placement).
+    The stream holds the metadata in lanes ``[0, meta_len)`` and the
+    payload from lane ``meta_max`` on. The pool may be given as its row
+    view ``[P, 1, page]``; the new pool comes back in the same shape.
+    Returns (meta_buf, new_pool)."""
+    return _anchor_ref(stream, meta_len, total_len, pool, tables, stream,
+                       meta_max=meta_max)
 
 
 def selective_copy_crypto_ref(
-    stream: jax.Array,    # [B, S] int32 ciphertext token stream
+    stream: jax.Array,    # [B, S] int32 ciphertext stream (or [B, 1, S])
     meta_len: jax.Array,  # [B] metadata boundary from the parser policy
-    total_len: jax.Array, # [B] message length in the stream
-    pool: jax.Array,      # [P, page] anchored payload pages
+    total_len: jax.Array, # [B] message length (metadata + payload)
+    pool: jax.Array,      # [P, page] anchored payload pages (or [P, 1, page])
     tables: jax.Array,    # [B, pps] destination page ids (-1 unused)
-    keystream: jax.Array, # [B, S] per-token keystream (0 outside payload)
+    keystream: jax.Array, # stream-shaped keystream (0 outside payload)
     *,
     meta_max: int,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -124,30 +144,14 @@ def selective_copy_crypto_ref(
     placement pass. The metadata compaction stays raw (record headers are
     plaintext; inner-metadata decryption happens host-side during the user
     copy, where the bytes are being touched anyway)."""
-    b, s = stream.shape
-    p_, page = pool.shape
-    pps = tables.shape[1]
-    idx = jnp.arange(meta_max)
-    meta_buf = jnp.where(idx[None, :] < meta_len[:, None],
-                         jnp.take_along_axis(
-                             stream, jnp.minimum(idx[None, :], s - 1), axis=1),
-                         0)
-    plain = jnp.bitwise_xor(stream, keystream.astype(stream.dtype))
-    t = jnp.arange(s)
-    rel = t[None, :] - meta_len[:, None]
-    valid = (rel >= 0) & (t[None, :] < total_len[:, None])
-    pg = jnp.clip(rel // page, 0, pps - 1)
-    dest_page = jnp.take_along_axis(tables, pg, axis=1)
-    dest_off = rel % page
-    flat_dest = jnp.where(valid & (dest_page >= 0),
-                          dest_page * page + dest_off, p_ * page)
-    new_pool = pool.reshape(-1).at[flat_dest.reshape(-1)].set(
-        plain.reshape(-1).astype(pool.dtype), mode="drop").reshape(p_, page)
-    return meta_buf, new_pool
+    stream = _flat(stream)
+    plain = jnp.bitwise_xor(stream, _flat(keystream).astype(stream.dtype))
+    return _anchor_ref(stream, meta_len, total_len, pool, tables, plain,
+                       meta_max=meta_max)
 
 
 def selective_gather_ref(
-    pool: jax.Array,      # [P+1, page] anchored payload pages (+ scratch row)
+    pool: jax.Array,      # [P+1, page] pages + scratch row (or [P+1, 1, page])
     tables: jax.Array,    # [B, pps] source page ids (-1 unused)
     lengths: jax.Array,   # [B] payload lengths
     keystream: Optional[jax.Array] = None,  # [B, pps*page] or None
@@ -158,14 +162,14 @@ def selective_gather_ref(
     past the length — and lanes of invalid (-1) table slots — are zero.
     ``keystream`` (payload-relative) is XORed into the gathered tokens
     inside the same pass (hw-kTLS NIC-inline TX encrypt)."""
-    p_, page = pool.shape
+    page = pool.shape[-1]
     b, pps = tables.shape
-    out = pool[jnp.clip(tables, 0)].reshape(b, pps * page)
+    out = pool.reshape(-1, page)[jnp.clip(tables, 0)].reshape(b, pps * page)
     pos = jnp.arange(pps * page)
     valid = (jnp.repeat(tables >= 0, page, axis=1)
              & (pos[None, :] < lengths[:, None]))
     if keystream is not None:
-        out = jnp.bitwise_xor(out, keystream.astype(out.dtype))
+        out = jnp.bitwise_xor(out, _flat(keystream).astype(out.dtype))
     return jnp.where(valid, out, 0)
 
 
@@ -227,7 +231,7 @@ def fused_round_ref(
     stream: jax.Array,     # [B, S] int32 token stream
     meta_len: jax.Array,   # [B] int32
     total_len: jax.Array,  # [B] int32
-    pool: jax.Array,       # [P+1, page] int32 (+ reserved scratch row)
+    pool: jax.Array,       # [P+1, page] int32 + scratch row (or [P+1, 1, page])
     tables: jax.Array,     # [B, pps] int32 page ids (-1 unused)
     *,
     meta_max: int,
@@ -244,7 +248,9 @@ def fused_round_ref(
     the first anchored page) + egress gather, composed from the per-pass
     references. Returns ``(meta [B, meta_max], new_pool, verdict [B] |
     None, out [B, pps*page])`` — the exact semantics
-    ``selective_copy.fused_round`` must reproduce."""
+    ``selective_copy.fused_round`` must reproduce. The stream and
+    keystreams may be given as their row views ``[B, 1, W]``."""
+    stream = _flat(stream)
     if keystream is None:
         meta, new_pool = selective_copy_ref(
             stream, meta_len, total_len, pool, tables, meta_max=meta_max)
@@ -253,17 +259,14 @@ def fused_round_ref(
         meta, new_pool = selective_copy_crypto_ref(
             stream, meta_len, total_len, pool, tables, keystream,
             meta_max=meta_max)
-        plain = jnp.bitwise_xor(stream, keystream.astype(stream.dtype))
+        plain = jnp.bitwise_xor(stream, _flat(keystream).astype(stream.dtype))
     plen = total_len - meta_len
     verdict = None
     if cond_off is not None:
-        b, s = stream.shape
-        page = pool.shape[1]
         # first-anchored-page window: payload-relative positions [0, page)
-        # (clamped in-stream; lanes past the payload are gated off by the
-        # ppos < payload_len check inside the match)
-        idx = jnp.minimum(meta_len[:, None] + jnp.arange(page)[None, :], s - 1)
-        window = jnp.take_along_axis(plain, idx, axis=1)
+        # (lanes past the payload are gated off by the ppos < payload_len
+        # check inside the match)
+        window = plain[:, meta_max : meta_max + pool.shape[-1]]
         mrow = meta if meta_ks is None else jnp.bitwise_xor(
             meta, meta_ks.astype(meta.dtype))
         verdict = policy_match_ref(mrow, meta_len, cond_off, cond_lo, cond_hi,

@@ -42,20 +42,77 @@ with the same optional ``keystream`` operand fusing the hw-kTLS TX encrypt
 into the gather — together the two kernels close the batched datapath loop
 entirely on-device.
 
-Layout: stream [B, S] int32; pool [P(+1), page] int32; tables [B, pps].
+Layout: stream [B, S] int32 with the metadata prefix in lanes ``[0,
+meta_len)`` and the payload from lane ``meta_max`` on (``S >= meta_max +
+pps*page``); pool [P(+1), page] int32; tables [B, pps]. Inside the kernels
+every [N, W] operand is viewed as [N, 1, W], so a one-row block spans the
+two tiled dims whole. The pool, the stream and the keystreams may also be
+passed already in that row view: on a TPU a [N, W] array is tiled (8, 128)
+and its row view (1, 128), so reshaping on the device is a relayout copy,
+while the host builds the row view for free (the resident
+:class:`~repro.core.device_pool.DevicePool` keeps its pool so, and
+``LibraStack`` builds its round operands so). A row-view pool comes back
+in the same shape. Compiled (non-interpret) calls need ``page`` and
+``meta_max`` to be multiples of :data:`LANE`, so every payload window
+starts on a lane tile; the host builds ``meta_max`` with
+:func:`stream_meta_width`.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+#: lanes in one 32-bit TPU vector tile: compiled kernels slice the stream
+#: and move pages only at multiples of it
+LANE = 128
 
-def _fused_kernel(mlen_ref, tlen_ref, tables_ref, stream_ref, *rest,
-                  page: int, s: int, meta_max: int, has_ks: bool):
+
+def stream_meta_width(meta_len_max: int, page: int) -> int:
+    """``meta_max`` for a round whose longest metadata prefix is
+    ``meta_len_max``: the metadata region rounded up to whole multiples of
+    ``gcd(page, LANE)`` — a lane tile for every page size the compiled
+    kernels accept, so the payload behind it starts lane-aligned, and no
+    wider than a page's alignment needs elsewhere."""
+    align = math.gcd(page, LANE)
+    return max(-(-meta_len_max // align), 1) * align
+
+
+def _check_tiling(interpret: bool, **dims: int) -> None:
+    """Compiled kernels move pages and slice the stream at lane-tile
+    offsets only; refuse other sizes instead of miscompiling them."""
+    bad = {k: v for k, v in dims.items() if v % LANE}
+    if bad and not interpret:
+        raise ValueError(
+            f"compiled selective-copy kernels need {sorted(dims)} to be "
+            f"multiples of {LANE}, got {bad}; build the stream with "
+            f"stream_meta_width() and a lane-multiple page size, or run "
+            f"impl='interpret'/'ref'")
+
+
+def _rows(x: jax.Array) -> jax.Array:
+    """[N, W] -> the row view [N, 1, W] (a no-op on a row view)."""
+    return x.reshape(x.shape[0], 1, x.shape[-1])
+
+
+def _meta_window(window: jax.Array, mlen) -> jax.Array:
+    """The [1, M] metadata window with lanes past ``mlen`` zeroed."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, window.shape, 1)
+    return jnp.where(idx < mlen, window, 0)
+
+
+def _payload_start(jj, *, meta_max: int, page: int):
+    """Stream lane of payload page ``jj`` — a multiple of both sizes'
+    common divisor, which the compiled path makes a lane tile."""
+    return pl.multiple_of(meta_max + jj * page, math.gcd(meta_max, page))
+
+
+def _anchor_kernel(mlen_ref, tlen_ref, tables_ref, stream_ref, *rest,
+                   page: int, pps: int, meta_max: int, has_ks: bool):
     if has_ks:
         ks_ref, pool_in_ref, meta_ref, pool_ref = rest
     else:
@@ -67,32 +124,26 @@ def _fused_kernel(mlen_ref, tlen_ref, tables_ref, stream_ref, *rest,
 
     @pl.when(j == 0)
     def _meta():
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, meta_max), 1)
-        window = stream_ref[0, :meta_max]
-        meta_ref[0, :] = jnp.where(idx[0] < mlen, window, 0)
+        meta_ref[...] = _meta_window(stream_ref[:, :meta_max], mlen)
 
     # payload step: j == 0 is aimed at the scratch row by the index map and
     # must pass the block through untouched (valid is forced False below)
     jj = jnp.maximum(j - 1, 0)
-    pid = tables_ref[b, jj]
-    start = jnp.minimum(mlen + jj * page, s - page)  # in-bounds (caller pads S)
-    # row index as a size-1 dslice: older pallas interpret-mode discharge
-    # rules reject plain-int indices mixed with dynamic slices
-    toks = pl.load(stream_ref, (pl.dslice(0, 1), pl.dslice(start, page)))[0]
+    pid = tables_ref[b * pps + jj]
+    start = _payload_start(jj, meta_max=meta_max, page=page)
+    toks = stream_ref[:, pl.ds(start, page)]
     if has_ks:
         # hw-kTLS: decrypt on the fly, inside the one placement pass
-        kst = pl.load(ks_ref, (pl.dslice(0, 1), pl.dslice(start, page)))[0]
-        toks = jnp.bitwise_xor(toks, kst)
-    rel = jj * page + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
+        toks = jnp.bitwise_xor(toks, ks_ref[:, pl.ds(start, page)])
+    rel = jj * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = (j > 0) & (pid >= 0) & (rel + mlen < tlen)
     # always write the block: invalid lanes / skipped pages pass the original
     # page content through (the scratch block is revisited via the routed index)
-    cur = pool_in_ref[0, :]
-    pool_ref[0, :] = jnp.where(valid, toks, cur)
+    pool_ref[...] = jnp.where(valid, toks, pool_in_ref[...])
 
 
 def _selective_copy_impl(
-    stream: jax.Array,    # [B, S] int32
+    stream: jax.Array,    # [B, S] int32 (or its row view [B, 1, S])
     meta_len: jax.Array,  # [B] int32
     total_len: jax.Array, # [B] int32
     pool: jax.Array,      # [P, page] int32 (donated); [P+1, page] w/ scratch
@@ -101,7 +152,7 @@ def _selective_copy_impl(
     meta_max: int,
     interpret: bool = False,
     reserved_scratch: bool = False,
-    keystream: jax.Array = None,   # [B, S] int32 (hw-kTLS) or None
+    keystream: jax.Array = None,   # stream-shaped int32 (hw-kTLS) or None
 ):
     """Returns (meta_buf [B, meta_max], new_pool). Matches
     kernels.ref.selective_copy_ref (selective_copy_crypto_ref when a
@@ -121,63 +172,66 @@ def _selective_copy_impl(
     with outer-level donation and device rounds keep ONE pool buffer
     instead of an input + an output copy. Callers of the donated entry
     must not touch their pool array afterwards (XLA deletes it)."""
-    b, s = stream.shape
-    page = pool.shape[1]
+    b, s = stream.shape[0], stream.shape[-1]      # [B, S] or [B, 1, S]
+    page = pool.shape[-1]
     pps = tables.shape[1]
-    assert s % page == 0, (s, page)
+    assert s >= meta_max + pps * page, (s, meta_max, pps, page)
+    _check_tiling(interpret, page=page, meta_max=meta_max)
     has_ks = keystream is not None
     if has_ks:
-        assert keystream.shape == stream.shape, (keystream.shape, stream.shape)
+        assert _rows(keystream).shape == (b, 1, s), (keystream.shape, s)
 
-    if reserved_scratch:
-        pool_ext = pool                     # last row IS the reserved scratch
-    else:
+    pool_ext = _rows(pool)
+    if not reserved_scratch:
         # legacy callers hold a scratch-less pool: append a dummy row (one
         # pool-sized copy — the batched datapath never takes this branch)
         pool_ext = jnp.concatenate(
-            [pool, jnp.zeros((1, page), pool.dtype)], axis=0)
+            [pool_ext, jnp.zeros((1, 1, page), pool.dtype)], axis=0)
     p_ext = pool_ext.shape[0]
     scratch = p_ext - 1
 
     def _pool_index(b_, j, ml, tl, tbl):
         # invalid table entries (-1) and the metadata step are routed to the
         # scratch row so no real page is ever revisited by a non-owner step
-        pid = tbl[b_, jnp.maximum(j - 1, 0)]
-        return (jnp.where((j == 0) | (pid < 0), scratch, pid), 0)
+        pid = tbl[b_ * pps + jnp.maximum(j - 1, 0)]
+        return (jnp.where((j == 0) | (pid < 0), scratch, pid), 0, 0)
 
-    stream_spec = pl.BlockSpec((1, s), lambda b_, j, ml, tl, tbl: (b_, 0))
+    stream_spec = pl.BlockSpec((None, 1, s),
+                               lambda b_, j, ml, tl, tbl: (b_, 0, 0))
+    page_spec = pl.BlockSpec((None, 1, page), _pool_index)
     in_specs = [stream_spec]
-    operands = [stream]
+    operands = [_rows(stream)]
     if has_ks:
         in_specs.append(stream_spec)        # keystream rides the stream layout
-        operands.append(keystream)
-    in_specs.append(pl.BlockSpec((1, page), _pool_index))
+        operands.append(_rows(keystream))
+    in_specs.append(page_spec)
     operands.append(pool_ext)
 
     meta, new_pool = pl.pallas_call(
-        functools.partial(_fused_kernel, page=page, s=s, meta_max=meta_max,
-                          has_ks=has_ks),
+        functools.partial(_anchor_kernel, page=page, pps=pps,
+                          meta_max=meta_max, has_ks=has_ks),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, 1 + pps),
             in_specs=in_specs,
             out_specs=[
-                pl.BlockSpec((1, meta_max), lambda b_, j, ml, tl, tbl: (b_, 0)),
-                pl.BlockSpec((1, page), _pool_index),
+                pl.BlockSpec((None, 1, meta_max),
+                             lambda b_, j, ml, tl, tbl: (b_, 0, 0)),
+                page_spec,
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((b, meta_max), stream.dtype),
-            jax.ShapeDtypeStruct((p_ext, page), pool.dtype),
+            jax.ShapeDtypeStruct((b, 1, meta_max), stream.dtype),
+            jax.ShapeDtypeStruct(pool_ext.shape, pool.dtype),
         ],
         # pool donated -> in-place anchoring (operand index counts the 3
         # scalar-prefetch args, the stream, and the optional keystream)
         input_output_aliases={(5 if has_ks else 4): 1},
         interpret=interpret,
-    )(meta_len, total_len, tables, *operands)
-    if reserved_scratch:
-        return meta, new_pool
-    return meta, new_pool[: p_ext - 1]
+    )(meta_len, total_len, tables.reshape(-1), *operands)
+    if not reserved_scratch:
+        new_pool = new_pool[:-1]
+    return meta.reshape(b, meta_max), new_pool.reshape(pool.shape)
 
 
 _JIT_STATICS = ("meta_max", "interpret", "reserved_scratch")
@@ -201,37 +255,53 @@ PAD_COND = -1
 PAYLOAD_COND_BASE = -2
 
 
-def _policy_rule_match(row, mlen, off, lo, hi, *, m: int, r: int, k: int,
-                       payload=None, plen=None, w: int = 0):
+def _policy_rule_match(row, mlen, off, lo, hi, *, payload=None, plen=None):
     """Shared condition-evaluation body for the standalone policy kernel
-    and the fused round: metadata conditions gather ``row[off]`` via a
-    one-hot lane mask (no dynamic indexing); payload-prefix conditions
-    (``off <= -2``) gather position ``-off - 2`` of the first anchored
-    page window the same way. Returns the [R] rule_ok mask."""
-    lane = jax.lax.broadcasted_iota(jnp.int32, (r * k, m), 1)
-    oh = lane == off.reshape(r * k, 1)
-    vals = jnp.sum(jnp.where(oh, jnp.broadcast_to(row[None, :], (r * k, m)),
-                             0), axis=1).reshape(r, k)
-    pad = off == PAD_COND
-    present = (off >= 0) & (off < mlen) & (off < m)
-    ok = pad | (present & (vals >= lo) & (vals <= hi))
+    and the fused round. ``row`` is the [1, M] metadata window, ``off`` /
+    ``lo`` / ``hi`` the [R, K] table. Each of the K condition columns is
+    evaluated for all R rules at once: metadata conditions gather
+    ``row[off]`` via a one-hot lane mask (no dynamic indexing);
+    payload-prefix conditions (``off <= -2``) gather position ``-off - 2``
+    of the [1, W] first anchored page window the same way. Returns the
+    [R, 1] rule_ok mask."""
+    r, k = off.shape
+    m = row.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r, m), 1)
     if payload is not None:
-        # payload-prefix conditions: position -off-2 of the message's
-        # first anchored page, gated on the window and the payload length
-        ppos = PAYLOAD_COND_BASE - off
-        plane = jax.lax.broadcasted_iota(jnp.int32, (r * k, w), 1)
-        poh = plane == ppos.reshape(r * k, 1)
-        pvals = jnp.sum(
-            jnp.where(poh, jnp.broadcast_to(payload[None, :], (r * k, w)), 0),
-            axis=1).reshape(r, k)
-        pay_ok = (off <= PAYLOAD_COND_BASE) & (ppos < plen) & (ppos < w) \
-            & (pvals >= lo) & (pvals <= hi)
-        ok = ok | pay_ok
-    return jnp.all(ok, axis=1)                             # [R]
+        w = payload.shape[1]
+        plane = jax.lax.broadcasted_iota(jnp.int32, (r, w), 1)
+    rule_ok = None
+    for c in range(k):
+        o, lo_c, hi_c = off[:, c:c + 1], lo[:, c:c + 1], hi[:, c:c + 1]
+        vals = jnp.sum(jnp.where(lane == o, row, 0), axis=1, keepdims=True)
+        present = (o >= 0) & (o < mlen) & (o < m)
+        ok = (o == PAD_COND) | (present & (vals >= lo_c) & (vals <= hi_c))
+        if payload is not None:
+            # payload-prefix conditions: position -off-2 of the message's
+            # first anchored page, gated on the window and the payload length
+            ppos = PAYLOAD_COND_BASE - o
+            pvals = jnp.sum(jnp.where(plane == ppos, payload, 0), axis=1,
+                            keepdims=True)
+            ok = ok | ((o <= PAYLOAD_COND_BASE) & (ppos < plen) & (ppos < w)
+                       & (pvals >= lo_c) & (pvals <= hi_c))
+        rule_ok = ok if rule_ok is None else rule_ok & ok
+    return rule_ok                                         # [R, 1]
 
 
-def _policy_kernel(*refs, m: int, r: int, k: int,
-                   has_ks: bool, has_live: bool, has_payload: bool, w: int):
+def _store_verdict(out_ref, rule_ok, live_ref) -> None:
+    """First matching rule index (``R`` = no match) over the [R, 1]
+    ``rule_ok`` mask, broadcast across the [1, LANE] verdict block."""
+    r = rule_ok.shape[0]
+    if live_ref is not None:
+        # backend-health column: dead rules (every backend down) never
+        # win the first-match scan — failover priority in-plane
+        rule_ok &= live_ref[...] > 0
+    ridx = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0)
+    first = jnp.min(jnp.where(rule_ok, ridx, r), axis=0, keepdims=True)
+    out_ref[...] = jnp.broadcast_to(first, out_ref.shape)
+
+
+def _policy_kernel(*refs, has_ks: bool, has_live: bool, has_payload: bool):
     refs = list(refs)
     mlen_ref = refs.pop(0)
     plen_ref = refs.pop(0) if has_payload else None
@@ -243,22 +313,16 @@ def _policy_kernel(*refs, m: int, r: int, k: int,
     payload_ref = refs.pop(0) if has_payload else None
     (out_ref,) = refs
     b = pl.program_id(0)
-    mlen = mlen_ref[b]
-    row = meta_ref[0, :]                                   # [M]
+    row = meta_ref[...]                                    # [1, M]
     if has_ks:
         # hw-kTLS: match against decrypted metadata — the keystream XOR
         # fused into the match pass, no separate decrypt
-        row = jnp.bitwise_xor(row, ks_ref[0, :])
+        row = jnp.bitwise_xor(row, ks_ref[...])
     rule_ok = _policy_rule_match(
-        row, mlen, off_ref[:, :], lo_ref[:, :], hi_ref[:, :], m=m, r=r, k=k,
-        payload=payload_ref[0, :] if has_payload else None,
-        plen=plen_ref[b] if has_payload else None, w=w)
-    if has_live:
-        # backend-health column: dead rules (every backend down) never
-        # win the first-match scan — failover priority in-plane
-        rule_ok &= live_ref[0, :] > 0
-    ridx = jax.lax.broadcasted_iota(jnp.int32, (r,), 0)
-    out_ref[0, 0] = jnp.min(jnp.where(rule_ok, ridx, r))
+        row, mlen_ref[b], off_ref[...], lo_ref[...], hi_ref[...],
+        payload=payload_ref[...] if has_payload else None,
+        plen=plen_ref[b] if has_payload else None)
+    _store_verdict(out_ref, rule_ok, live_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -300,64 +364,64 @@ def policy_match(
         assert keystream.shape == meta.shape, (keystream.shape, meta.shape)
     has_live = live is not None
     has_payload = payload is not None
-    w = payload.shape[1] if has_payload else 0
     if has_payload:
         assert payload.shape[0] == b and payload_len is not None, \
             (payload.shape, b)
 
-    meta_spec = pl.BlockSpec((1, m), lambda b_, *_: (b_, 0))
+    meta_spec = pl.BlockSpec((None, 1, m), lambda b_, *_: (b_, 0, 0))
     table_spec = pl.BlockSpec((r, k), lambda b_, *_: (0, 0))
     in_specs = [meta_spec]
-    operands = [meta]
+    operands = [_rows(meta)]
     if has_ks:
         in_specs.append(meta_spec)       # keystream rides the meta layout
-        operands.append(keystream)
+        operands.append(_rows(keystream))
     in_specs += [table_spec, table_spec, table_spec]
     operands += [cond_off, cond_lo, cond_hi]
     if has_live:
         assert live.shape == (r,), (live.shape, r)
-        in_specs.append(pl.BlockSpec((1, r), lambda b_, *_: (0, 0)))
-        operands.append(jnp.asarray(live, jnp.int32).reshape(1, r))
+        in_specs.append(pl.BlockSpec((r, 1), lambda b_, *_: (0, 0)))
+        operands.append(jnp.asarray(live, jnp.int32).reshape(r, 1))
     if has_payload:
-        in_specs.append(pl.BlockSpec((1, w), lambda b_, *_: (b_, 0)))
-        operands.append(payload)
+        w = payload.shape[1]
+        in_specs.append(pl.BlockSpec((None, 1, w), lambda b_, *_: (b_, 0, 0)))
+        operands.append(_rows(payload))
         prefetch = (meta_len, jnp.asarray(payload_len, jnp.int32))
     else:
         prefetch = (meta_len,)
 
     out = pl.pallas_call(
-        functools.partial(_policy_kernel, m=m, r=r, k=k, has_ks=has_ks,
-                          has_live=has_live, has_payload=has_payload, w=w),
+        functools.partial(_policy_kernel, has_ks=has_ks, has_live=has_live,
+                          has_payload=has_payload),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1), lambda b_, *_: (b_, 0)),
+            out_specs=pl.BlockSpec((None, 1, LANE),
+                                   lambda b_, *_: (b_, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, LANE), jnp.int32),
         interpret=interpret,
     )(*prefetch, *operands)
-    return out[:, 0]
+    return out[:, 0, 0]
 
 
 def _gather_kernel(len_ref, tables_ref, pool_ref, *rest,
-                   page: int, has_ks: bool):
+                   page: int, pps: int, has_ks: bool):
     if has_ks:
         ks_ref, out_ref = rest
     else:
         (out_ref,) = rest
     b = pl.program_id(0)
     j = pl.program_id(1)   # output page slot j covers payload [j*page, ...)
-    pid = tables_ref[b, j]
-    ln = len_ref[b]
-    rel = j * page + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
-    valid = (pid >= 0) & (rel < ln)
-    toks = pool_ref[0, :]
+    pid = tables_ref[b * pps + j]
+    rel = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+    valid = (pid >= 0) & (rel < len_ref[b])
+    toks = pool_ref[...]
     if has_ks:
         # hw-kTLS TX: encrypt inline while consuming the anchored page —
         # the same fused single pass as the ingress decrypt
-        toks = jnp.bitwise_xor(toks, ks_ref[0, :])
-    out_ref[0, :] = jnp.where(valid, toks, 0)
+        toks = jnp.bitwise_xor(toks, ks_ref[...])
+    out_ref[...] = jnp.where(valid, toks, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -382,43 +446,44 @@ def selective_gather(
     ``keystream`` (payload-relative, zeros past each length) is XORed into
     the gathered tokens inside the same pass — NIC-inline TX encryption,
     zero extra passes. Matches ``kernels.ref.selective_gather_ref``."""
-    p_ext, page = pool.shape
+    p_ext, page = pool.shape[0], pool.shape[-1]
     b, pps = tables.shape
+    _check_tiling(interpret, page=page)
     scratch = p_ext - 1
     has_ks = keystream is not None
     if has_ks:
-        assert keystream.shape == (b, pps * page), \
+        assert _rows(keystream).shape == (b, 1, pps * page), \
             (keystream.shape, (b, pps * page))
 
     def _pool_index(b_, j, ln, tbl):
-        pid = tbl[b_, j]
-        return (jnp.where(pid < 0, scratch, pid), 0)
+        pid = tbl[b_ * pps + j]
+        return (jnp.where(pid < 0, scratch, pid), 0, 0)
 
-    in_specs = [pl.BlockSpec((1, page), _pool_index)]
-    operands = [pool]
+    slot_spec = pl.BlockSpec((None, 1, page), lambda b_, j, ln, tbl: (b_, 0, j))
+    in_specs = [pl.BlockSpec((None, 1, page), _pool_index)]
+    operands = [_rows(pool)]
     if has_ks:
-        in_specs.append(pl.BlockSpec((1, page), lambda b_, j, ln, tbl: (b_, j)))
-        operands.append(keystream)
+        in_specs.append(slot_spec)
+        operands.append(_rows(keystream))
 
     out = pl.pallas_call(
-        functools.partial(_gather_kernel, page=page, has_ks=has_ks),
+        functools.partial(_gather_kernel, page=page, pps=pps, has_ks=has_ks),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, pps),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, page), lambda b_, j, ln, tbl: (b_, j)),
+            out_specs=slot_spec,
         ),
-        out_shape=jax.ShapeDtypeStruct((b, pps * page), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, 1, pps * page), pool.dtype),
         interpret=interpret,
-    )(lengths, tables, *operands)
-    return out
+    )(lengths, tables.reshape(-1), *operands)
+    return out.reshape(b, pps * page)
 
 
 def _fused_round_kernel(mlen_ref, tlen_ref, tables_ref, *refs,
-                        page: int, s: int, meta_max: int, b_rows: int,
-                        r: int, k: int, has_ks: bool, has_txks: bool,
-                        has_policy: bool, has_live: bool, has_meta_ks: bool,
-                        n_buffers: int):
+                        page: int, pps: int, meta_max: int, b_rows: int,
+                        has_ks: bool, has_txks: bool, has_policy: bool,
+                        has_live: bool, has_meta_ks: bool, n_buffers: int):
     refs = list(refs)
     stream_ref = refs.pop(0)
     ks_ref = refs.pop(0) if has_ks else None
@@ -454,40 +519,32 @@ def _fused_round_kernel(mlen_ref, tlen_ref, tables_ref, *refs,
         # safe under the sequential grid: row b + D - 1 lands in slot
         # (b - 1) % D, whose previous owner (row b - 1) ran its last grid
         # step before (b, 0) executes.
-        def _start(row):
+        def _copies(row):
             slot = row % n_buffers
-            pltpu.make_async_copy(stream_ref.at[pl.ds(row, 1), :],
-                                  stream_buf.at[slot],
-                                  stream_sem.at[slot]).start()
+            yield pltpu.make_async_copy(stream_ref.at[row],
+                                        stream_buf.at[slot],
+                                        stream_sem.at[slot])
             if has_ks:
-                pltpu.make_async_copy(ks_ref.at[pl.ds(row, 1), :],
-                                      ks_buf.at[slot],
-                                      ks_sem.at[slot]).start()
-
-        def _wait(row):
-            slot = row % n_buffers
-            pltpu.make_async_copy(stream_ref.at[pl.ds(row, 1), :],
-                                  stream_buf.at[slot],
-                                  stream_sem.at[slot]).wait()
-            if has_ks:
-                pltpu.make_async_copy(ks_ref.at[pl.ds(row, 1), :],
-                                      ks_buf.at[slot],
-                                      ks_sem.at[slot]).wait()
+                yield pltpu.make_async_copy(ks_ref.at[row], ks_buf.at[slot],
+                                            ks_sem.at[slot])
 
         @pl.when(j == 0)
         def _dma():
             @pl.when(b == 0)
             def _warm_up():
                 for i in range(min(n_buffers - 1, b_rows)):
-                    _start(i)
+                    for cp in _copies(i):
+                        cp.start()
 
             nxt = b + n_buffers - 1
 
             @pl.when(nxt < b_rows)
             def _prefetch_ahead():
-                _start(nxt)
+                for cp in _copies(nxt):
+                    cp.start()
 
-            _wait(b)
+            for cp in _copies(b):
+                cp.wait()
 
     def _load_row(start, width, ks=False):
         # one row window [start, start+width) of the stream (or keystream):
@@ -495,22 +552,21 @@ def _fused_round_kernel(mlen_ref, tlen_ref, tables_ref, *refs,
         # the blocked operand directly
         if n_buffers:
             buf = ks_buf if ks else stream_buf
-            return pl.load(buf, (pl.dslice(b % n_buffers, 1), pl.dslice(0, 1),
-                                 pl.dslice(start, width)))[0, 0]
+            return buf[b % n_buffers, :, pl.ds(start, width)]
         ref = ks_ref if ks else stream_ref
-        return pl.load(ref, (pl.dslice(0, 1), pl.dslice(start, width)))[0]
+        return ref[:, pl.ds(start, width)]
 
     # ---- anchor + egress gather (j >= 1; j == 0 routed to scratch) ----
     jj = jnp.maximum(j - 1, 0)
-    pid = tables_ref[b, jj]
-    start = jnp.minimum(mlen + jj * page, s - page)  # in-bounds (caller pads S)
+    pid = tables_ref[b * pps + jj]
+    start = _payload_start(jj, meta_max=meta_max, page=page)
     toks = _load_row(start, page)
     if has_ks:
         # hw-kTLS RX: decrypt on the fly, inside the one placement pass
         toks = jnp.bitwise_xor(toks, _load_row(start, page, ks=True))
-    rel = jj * page + jax.lax.broadcasted_iota(jnp.int32, (page,), 0)
+    rel = jj * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
     valid = (j > 0) & (pid >= 0) & (rel + mlen < tlen)
-    pool_ref[0, :] = jnp.where(valid, toks, pool_in_ref[0, :])
+    pool_ref[...] = jnp.where(valid, toks, pool_in_ref[...])
 
     @pl.when(j > 0)
     def _gather():
@@ -520,44 +576,37 @@ def _fused_round_kernel(mlen_ref, tlen_ref, tables_ref, *refs,
         gtoks = toks
         if has_txks:
             # speculative hw-kTLS TX encrypt for the hinted destination
-            gtoks = jnp.bitwise_xor(gtoks, txks_ref[0, :])
-        out_ref[0, :] = jnp.where(valid, gtoks, 0)
+            gtoks = jnp.bitwise_xor(gtoks, txks_ref[...])
+        out_ref[...] = jnp.where(valid, gtoks, 0)
 
     @pl.when(j == 0)
     def _meta():
-        idx = jax.lax.broadcasted_iota(jnp.int32, (meta_max,), 0)
-        window = _load_row(0, meta_max)
-        meta_ref[0, :] = jnp.where(idx < mlen, window, 0)
+        window = _meta_window(_load_row(0, meta_max), mlen)
+        meta_ref[...] = window
         if has_policy:
-            plen = tlen - mlen
             row = window
             if has_meta_ks:
-                row = jnp.bitwise_xor(row, metaks_ref[0, :])
+                row = jnp.bitwise_xor(row, metaks_ref[...])
             # payload-prefix window: the first anchored page, decrypted
-            # with the same rx-keystream lanes the anchoring step consumes.
-            # Whenever plen >= 1 the caller's S >= mlen + page invariant
-            # makes the clamp a no-op; at plen == 0 the ppos < plen gate
-            # discards the window, so its content is irrelevant.
-            pstart = jnp.minimum(mlen, s - page)
-            prow = _load_row(pstart, page)
+            # with the same rx-keystream lanes the anchoring step consumes
+            # (lanes past the payload are gated off by ppos < plen)
+            prow = _load_row(meta_max, page)
             if has_ks:
-                prow = jnp.bitwise_xor(prow, _load_row(pstart, page, ks=True))
+                prow = jnp.bitwise_xor(prow, _load_row(meta_max, page,
+                                                       ks=True))
             rule_ok = _policy_rule_match(
-                row, mlen, off_ref[:, :], lo_ref[:, :], hi_ref[:, :],
-                m=meta_max, r=r, k=k, payload=prow, plen=plen, w=page)
-            if has_live:
-                rule_ok &= live_ref[0, :] > 0
-            ridx = jax.lax.broadcasted_iota(jnp.int32, (r,), 0)
-            verdict_ref[0, 0] = jnp.min(jnp.where(rule_ok, ridx, r))
+                row, mlen, off_ref[...], lo_ref[...], hi_ref[...],
+                payload=prow, plen=tlen - mlen)
+            _store_verdict(verdict_ref, rule_ok, live_ref)
 
 
 def _fused_round_impl(
-    stream: jax.Array,     # [B, S] int32
+    stream: jax.Array,     # [B, S] int32 (or its row view [B, 1, S])
     meta_len: jax.Array,   # [B] int32
     total_len: jax.Array,  # [B] int32
     pool: jax.Array,       # [P+1, page] int32; last row = reserved scratch
     tables: jax.Array,     # [B, pps] int32
-    keystream: jax.Array = None,      # [B, S] int32 hw-kTLS RX or None
+    keystream: jax.Array = None,      # stream-shaped hw-kTLS RX or None
     tx_keystream: jax.Array = None,   # [B, pps*page] int32 hw-kTLS TX or None
     cond_off: jax.Array = None,       # [R, K] int32 policy table or None
     cond_lo: jax.Array = None,
@@ -591,25 +640,26 @@ def _fused_round_impl(
     by :mod:`repro.kernels.dma_profile`). ``n_buffers == 0`` compiles the
     plain blocked layout.
 
-    Caller invariants (both hold for `_recv_batch_device` streams and
-    ``testing.fused_round_case``): ``S`` is page-aligned with ``S >=
-    meta_max``, and ``S >= meta_len[i] + pps_i * page`` per row, so the
-    page-window clamp never fires on a lane that passes the valid gate."""
-    b, s = stream.shape
-    p_ext, page = pool.shape
+    Caller invariant (``_recv_batch_fused`` streams and
+    ``testing.fused_round_case`` hold it): ``S >= meta_max + pps * page``,
+    so every payload page window lies inside the stream."""
+    b, s = stream.shape[0], stream.shape[-1]      # [B, S] or [B, 1, S]
+    p_ext, page = pool.shape[0], pool.shape[-1]
     pps = tables.shape[1]
-    assert s % page == 0 and s >= page and s >= meta_max, (s, page, meta_max)
     assert pps >= 1, "fused_round needs >= 1 table column (pad tables)"
+    assert s >= meta_max + pps * page, (s, meta_max, pps, page)
     assert n_buffers == 0 or n_buffers >= 2, n_buffers
+    _check_tiling(interpret, page=page, meta_max=meta_max)
     has_ks = keystream is not None
     has_txks = tx_keystream is not None
     has_policy = cond_off is not None
     has_live = live is not None
     has_meta_ks = meta_ks is not None
     if has_ks:
-        assert keystream.shape == stream.shape, (keystream.shape, stream.shape)
+        assert _rows(keystream).shape == (b, 1, s), (keystream.shape, s)
     if has_txks:
-        assert tx_keystream.shape == (b, pps * page), tx_keystream.shape
+        assert _rows(tx_keystream).shape == (b, 1, pps * page), \
+            tx_keystream.shape
     r = k = 0
     if has_policy:
         r, k = cond_off.shape
@@ -622,27 +672,31 @@ def _fused_round_impl(
     def _pool_index(b_, j, ml, tl, tbl):
         # invalid table entries (-1) and the metadata step are routed to the
         # scratch row so no real page is ever revisited by a non-owner step
-        pid = tbl[b_, jnp.maximum(j - 1, 0)]
-        return (jnp.where((j == 0) | (pid < 0), scratch, pid), 0)
+        pid = tbl[b_ * pps + jnp.maximum(j - 1, 0)]
+        return (jnp.where((j == 0) | (pid < 0), scratch, pid), 0, 0)
 
     if n_buffers:
         # stream rows live off-chip and are staged by the kernel's own DMAs
-        stream_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
+        stream_spec = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
     else:
-        stream_spec = pl.BlockSpec((1, s), lambda b_, j, ml, tl, tbl: (b_, 0))
-    row_spec = pl.BlockSpec((1, meta_max), lambda b_, j, ml, tl, tbl: (b_, 0))
+        stream_spec = pl.BlockSpec((None, 1, s),
+                                   lambda b_, j, ml, tl, tbl: (b_, 0, 0))
+    row_spec = pl.BlockSpec((None, 1, meta_max),
+                            lambda b_, j, ml, tl, tbl: (b_, 0, 0))
     gather_spec = pl.BlockSpec(
-        (1, page), lambda b_, j, ml, tl, tbl: (b_, jnp.maximum(j - 1, 0)))
+        (None, 1, page),
+        lambda b_, j, ml, tl, tbl: (b_, 0, jnp.maximum(j - 1, 0)))
+    page_spec = pl.BlockSpec((None, 1, page), _pool_index)
     in_specs = [stream_spec]
-    operands = [stream]
+    operands = [_rows(stream)]
     if has_ks:
         in_specs.append(stream_spec)        # keystream rides the stream layout
-        operands.append(keystream)
+        operands.append(_rows(keystream))
     if has_txks:
         in_specs.append(gather_spec)        # payload-relative TX keystream
-        operands.append(tx_keystream)
-    in_specs.append(pl.BlockSpec((1, page), _pool_index))
-    operands.append(pool)
+        operands.append(_rows(tx_keystream))
+    in_specs.append(page_spec)
+    operands.append(_rows(pool))
     # pool operand index counts the 3 scalar-prefetch args
     pool_operand = 3 + len(operands) - 1
     if has_policy:
@@ -651,23 +705,21 @@ def _fused_round_impl(
         operands += [cond_off, cond_lo, cond_hi]
         if has_meta_ks:
             in_specs.append(row_spec)
-            operands.append(meta_ks)
+            operands.append(_rows(meta_ks))
         if has_live:
             assert live.shape == (r,), (live.shape, r)
             in_specs.append(
-                pl.BlockSpec((1, r), lambda b_, j, ml, tl, tbl: (0, 0)))
-            operands.append(jnp.asarray(live, jnp.int32).reshape(1, r))
+                pl.BlockSpec((r, 1), lambda b_, j, ml, tl, tbl: (0, 0)))
+            operands.append(jnp.asarray(live, jnp.int32).reshape(r, 1))
 
-    out_specs = [row_spec,
-                 pl.BlockSpec((1, page), _pool_index),
-                 gather_spec]
-    out_shape = [jax.ShapeDtypeStruct((b, meta_max), stream.dtype),
-                 jax.ShapeDtypeStruct((p_ext, page), pool.dtype),
-                 jax.ShapeDtypeStruct((b, pps * page), stream.dtype)]
+    out_specs = [row_spec, page_spec, gather_spec]
+    out_shape = [jax.ShapeDtypeStruct((b, 1, meta_max), stream.dtype),
+                 jax.ShapeDtypeStruct((p_ext, 1, page), pool.dtype),
+                 jax.ShapeDtypeStruct((b, 1, pps * page), stream.dtype)]
     if has_policy:
-        out_specs.append(
-            pl.BlockSpec((1, 1), lambda b_, j, ml, tl, tbl: (b_, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((b, 1), jnp.int32))
+        out_specs.append(pl.BlockSpec(
+            (None, 1, LANE), lambda b_, j, ml, tl, tbl: (b_, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((b, 1, LANE), jnp.int32))
     scratch_shapes = []
     if n_buffers:
         scratch_shapes += [pltpu.VMEM((n_buffers, 1, s), stream.dtype),
@@ -677,11 +729,11 @@ def _fused_round_impl(
                                pltpu.SemaphoreType.DMA((n_buffers,))]
 
     res = pl.pallas_call(
-        functools.partial(_fused_round_kernel, page=page, s=s,
-                          meta_max=meta_max, b_rows=b, r=r, k=k,
-                          has_ks=has_ks, has_txks=has_txks,
-                          has_policy=has_policy, has_live=has_live,
-                          has_meta_ks=has_meta_ks, n_buffers=n_buffers),
+        functools.partial(_fused_round_kernel, page=page, pps=pps,
+                          meta_max=meta_max, b_rows=b, has_ks=has_ks,
+                          has_txks=has_txks, has_policy=has_policy,
+                          has_live=has_live, has_meta_ks=has_meta_ks,
+                          n_buffers=n_buffers),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, 1 + pps),
@@ -692,9 +744,10 @@ def _fused_round_impl(
         out_shape=out_shape,
         input_output_aliases={pool_operand: 1},
         interpret=interpret,
-    )(meta_len, total_len, tables, *operands)
-    verdict = res[3][:, 0] if has_policy else None
-    return res[0], res[1], verdict, res[2]
+    )(meta_len, total_len, tables.reshape(-1), *operands)
+    verdict = res[3][:, 0, 0] if has_policy else None
+    return (res[0].reshape(b, meta_max), res[1].reshape(pool.shape), verdict,
+            res[2].reshape(b, pps * page))
 
 
 _FUSED_STATICS = ("meta_max", "interpret", "n_buffers")

@@ -24,7 +24,9 @@ def selcopy_case(rng: np.random.Generator, b: int = 2, page: int = 8,
                  pps: int = 4, meta_max: int = 16) -> Tuple:
     """(stream, meta_len, total_len, pool_with_scratch, tables) with random
     parse boundaries; the pool's LAST row is the reserved scratch page
-    (slice it off for legacy-mode calls)."""
+    (slice it off for legacy-mode calls). The stream is random in every
+    lane: metadata prefix in ``[0, meta_len)``, payload from lane
+    ``meta_max`` on, as the kernels' layout places them."""
     s = meta_max + pps * page
     p_total = b * pps + 2
     stream = jnp.array(rng.integers(1, 1000, (b, s)), jnp.int32)
@@ -53,8 +55,8 @@ def selcopy_crypto_case(rng: np.random.Generator, b: int = 2, page: int = 8,
                                                 meta_max=meta_max)
     s = stream.shape[1]
     ks = rng.integers(0, 1 << 31, (b, s)).astype(np.int32)
-    pos = np.arange(s)[None, :]
-    payload_lane = (pos >= np.array(ml)[:, None]) & (pos < np.array(tl)[:, None])
+    rel = np.arange(s)[None, :] - meta_max                 # payload-relative
+    payload_lane = (rel >= 0) & (rel < (np.array(tl) - np.array(ml))[:, None])
     ks = np.where(payload_lane, ks, 0).astype(np.int32)
     return stream, ml, tl, pool, tables, jnp.array(ks)
 
@@ -146,7 +148,7 @@ def fused_round_case(rng: np.random.Generator, b: int = 2, page: int = 8,
     each payload length), a policy table mixing metadata / padding /
     payload-prefix conditions, a live health column, and a
     standalone-contract metadata keystream. Preserves the fused-round
-    caller invariant ``S = meta_max + pps*page >= meta_len + pps*page``.
+    caller invariant ``S = meta_max + pps*page``.
     Returned as a dict keyed by :func:`repro.kernels.ops.fused_round`
     argument names (drop keys to exercise the optional-operand matrix)."""
     stream, ml, tl, pool, tables, ks = selcopy_crypto_case(

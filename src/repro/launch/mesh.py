@@ -12,27 +12,26 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.common.sharding import AxisType, make_mesh
 from repro.common.types import MULTI_POD, SINGLE_POD, MeshSpec
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_from_spec(spec: MeshSpec) -> Mesh:
-    return make_mesh(spec.shape, spec.axes,
-                     axis_types=(AxisType.Auto,) * len(spec.axes))
+    return jax.make_mesh(spec.shape, spec.axes,
+                         axis_types=(AxisType.Auto,) * len(spec.axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over however many (host) devices exist — tests/examples."""
-    return make_mesh((data, model), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_spec_for(mesh: Mesh) -> MeshSpec:

@@ -31,7 +31,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.common.sharding import shard_map
 
 NEG_INF = -1e30
 
@@ -399,7 +398,7 @@ def paged_decode_attention(
         out = acc_g / jnp.maximum(l_g, 1e-30)[..., None]
         return out.reshape(b, hq, hd).astype(q.dtype), pool
 
-    shard = shard_map(
+    shard = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(bspec, bspec, bspec, pool_spec, bspec, bspec, bspec, bspec, bspec),
@@ -443,7 +442,7 @@ def prefill_write_pages(
             kv.reshape(b * s, 2, hkv, hd), mode="drop")
         return pool
 
-    shard = shard_map(
+    shard = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(bspec, bspec, pool_spec, bspec, bspec, bspec, bspec, bspec),

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.common.sharding import constrain, shard_map
+from repro.common.sharding import constrain
 from repro.common.types import ModelConfig
 from repro.models.layers import ParamSpec
 
@@ -195,7 +195,7 @@ def moe_ffn(
         shared_specs = (sf_spec, sf_spec, P("model", "data" if "data" in sizes
                                             else None), P(None, None))
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), w_spec, w_spec, wd_spec, shared_specs),
         out_specs=(x_spec, P()),

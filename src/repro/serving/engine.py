@@ -29,8 +29,8 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
-from repro.common.sharding import make_mesh
 from repro.core.anchor_pool import PoolExhausted
 from repro.core.parser import TokenStreamParser
 from repro.core.stack import LibraStack
@@ -94,7 +94,8 @@ class _EngineBase:
         self.active: List[Request] = []
         self.completed: List[Request] = []
         self._rid = 0
-        self.mesh = make_mesh((1, 1), ("data", "model"))
+        self.mesh = jax.make_mesh((1, 1), ("data", "model"),
+                                  axis_types=(AxisType.Auto,) * 2)
 
     def submit(self, prompt: np.ndarray, max_new_tokens: int = 16) -> Request:
         self._rid += 1

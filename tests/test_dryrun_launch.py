@@ -39,14 +39,14 @@ def test_small_mesh_train_and_decode_cells():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.common.sharding import AxisType, make_mesh
+        from jax.sharding import AxisType
         from repro.common.types import ShapeSpec, MeshSpec
         from repro.configs import get_reduced
         from repro.launch import dryrun
         from repro.roofline.hlo_analysis import analyze_hlo_text
 
-        mesh = make_mesh((2, 4), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         for arch, shape in [("phi4-mini-3.8b", ShapeSpec("t", 64, 8, "train")),
                             ("qwen2-moe-a2.7b", ShapeSpec("t", 64, 8, "train")),
                             ("phi3-mini-3.8b", ShapeSpec("d", 64, 8, "decode")),

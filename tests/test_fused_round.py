@@ -131,6 +131,38 @@ def test_fused_round_interpret_matches_oracle(crypto, policy, n_buffers):
         assert np.array_equal(np.array(gi), np.array(wi)), tag
 
 
+@pytest.mark.parametrize("impl", ["ref", "interpret"])
+@pytest.mark.parametrize("entry", ["fused_round", "selective_copy"])
+def test_row_view_streams_match_flat_streams(impl, entry):
+    """The host builds the stream, RX and TX keystreams as their [B, 1, W]
+    row view (no relayout on a TPU); every impl must give the same result
+    as for the flat [B, W] operands, with the flat oracle as the truth."""
+    rng = np.random.default_rng(29)
+    case = fused_round_case(rng, b=3, page=8, pps=2, meta_max=8)
+    base = (case["meta_len"], case["total_len"], case["pool"],
+            case["tables"])
+    flat = dict(keystream=case["keystream"])
+    if entry == "fused_round":
+        flat.update(tx_keystream=case["tx_keystream"],
+                    cond_off=case["cond_off"], cond_lo=case["cond_lo"],
+                    cond_hi=case["cond_hi"], live=case["live"],
+                    meta_ks=case["meta_ks"])
+        want = ref.fused_round_ref(case["stream"], *base, meta_max=8, **flat)
+    else:
+        want = ref.selective_copy_crypto_ref(
+            case["stream"], *base, flat["keystream"], meta_max=8)
+    rows = {k: (v[:, None, :] if k in ("keystream", "tx_keystream") else v)
+            for k, v in flat.items()}
+    extra = {} if entry == "fused_round" else dict(reserved_scratch=True)
+    got = getattr(ops, entry)(case["stream"][:, None, :], *base, meta_max=8,
+                              impl=impl, **rows, **extra)
+    for gi, wi in zip(got, want):
+        if wi is None:
+            assert gi is None
+            continue
+        assert np.array_equal(np.array(gi), np.array(wi))
+
+
 # ---------------------------------------------------------------------------
 # datapath identity: single stack, plaintext / hw-kTLS × policy, + scalar
 # ---------------------------------------------------------------------------

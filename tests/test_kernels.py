@@ -150,14 +150,15 @@ def test_selective_copy_property(data):
                                           jnp.array(tables), meta_max=meta_max)
     assert np.array_equal(np.array(got_m), np.array(want_m))
     assert np.array_equal(np.array(got_p), np.array(want_p))
-    # semantic checks against the raw stream
+    # semantic checks against the raw stream (payload from lane meta_max)
     for i in range(b):
         ml, tl = int(meta_len[i]), int(total_len[i])
         assert np.array_equal(np.array(got_m[i, :ml]), np.array(stream[i, :ml]))
         for j, pid in enumerate(tables[i]):
             if pid < 0:
                 continue
-            lo, hi = ml + j * page, min(ml + (j + 1) * page, tl)
+            lo = meta_max + j * page
+            hi = min(lo + page, meta_max + tl - ml)
             if hi > lo:
                 assert np.array_equal(np.array(got_p[pid, : hi - lo]),
                                       np.array(stream[i, lo:hi]))
@@ -209,6 +210,40 @@ def test_selective_copy_hot_path_has_no_pool_copy():
     lnames = jaxpr_primitives(jax.make_jaxpr(legacy)(stream, ml, tl,
                                                      pool[:-1], tables).jaxpr)
     assert "concatenate" in lnames
+
+
+@pytest.mark.parametrize("meta_len_max,page,want", [
+    (11, 16, 16), (11, 8, 16), (0, 16, 16), (20, 96, 32),
+    (129, 128, 256), (300, 4096, 384)])
+def test_stream_meta_width_aligns_to_page_and_lane(meta_len_max, page, want):
+    """The metadata region is padded to ``gcd(page, 128)``: a whole lane
+    tile at every page size the compiled kernels accept, and no wider than
+    the page's own alignment on the CPU paths' small pages."""
+    from repro.kernels.selective_copy import stream_meta_width
+
+    assert stream_meta_width(meta_len_max, page) == want
+
+
+@pytest.mark.parametrize("page,meta_max", [(8, 16), (128, 16), (96, 128)])
+def test_compiled_selective_copy_refuses_unaligned_layout(page, meta_max):
+    """The compiled (non-interpret) kernels move pages and slice the stream
+    at lane-tile offsets only: any other page or metadata width raises —
+    through ``ops`` too — instead of falling back to another impl."""
+    from repro.kernels.testing import fused_round_case
+
+    case = fused_round_case(np.random.default_rng(1), page=page,
+                            meta_max=meta_max)
+    base = (case["stream"], case["meta_len"], case["total_len"],
+            case["pool"], case["tables"])
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ops.fused_round(*base, meta_max=meta_max, impl="pallas")
+    with pytest.raises(ValueError, match="multiples of 128"):
+        ops.selective_copy(*base, meta_max=meta_max, impl="pallas",
+                           reserved_scratch=True)
+    if page % 128:
+        with pytest.raises(ValueError, match="multiples of 128"):
+            ops.selective_gather(case["pool"], case["tables"],
+                                 case["meta_len"], impl="pallas")
 
 
 # ---------------------------------------------------------------------------

@@ -7,8 +7,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.common.sharding import (
     DEFAULT_RULES,
-    AxisType,
-    abstract_mesh,
     fsdp2d_rules,
     spec_for,
     tree_shardings,
@@ -22,7 +20,7 @@ from repro.roofline.hlo_analysis import (
 
 
 def _mesh(shape=(2, 4), axes=("data", "model")):
-    return abstract_mesh(shape, axes)
+    return jax.sharding.AbstractMesh(tuple(shape), tuple(axes))
 
 
 class TestSpecFor:

@@ -7,8 +7,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
-from repro.common.sharding import make_mesh, shard_map
 from repro.configs import get_reduced
 from repro.data.pipeline import DataPipeline, SyntheticCorpus
 from repro.distributed.fault_tolerance import StragglerMonitor, plan_elastic_restart
@@ -80,7 +80,8 @@ def test_elastic_restore_other_mesh(tmp_path):
     t1 = _mk_trainer(str(tmp_path))
     t1.train(4)
     t1.save(blocking=True)
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     t2 = _mk_trainer(str(tmp_path))
     assert t2.resume(mesh=mesh)
     # params usable on the new mesh
@@ -113,7 +114,7 @@ def test_wsd_schedule_shape():
 def test_gradient_compression_close_to_exact():
     """int8 compressed psum with error feedback: single-participant mean
     must track the exact gradient closely; residual carries the error."""
-    mesh = make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     g = {"w": jnp.array(np.random.default_rng(0).standard_normal((64, 64)),
                         jnp.float32)}
     err = jax.tree.map(jnp.zeros_like, g)
@@ -121,7 +122,7 @@ def test_gradient_compression_close_to_exact():
     def f(g, err):
         return compressed_psum(g, "pod", err)
 
-    out, err2 = shard_map(
+    out, err2 = jax.shard_map(
         f, mesh=mesh, in_specs=(jax.sharding.PartitionSpec(),) * 2,
         out_specs=(jax.sharding.PartitionSpec(),) * 2, check_vma=False)(g, err)
     rel = float(jnp.linalg.norm(out["w"] - g["w"]) / jnp.linalg.norm(g["w"]))
