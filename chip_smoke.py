@@ -35,7 +35,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-import time
 from pathlib import Path
 
 import numpy as np
@@ -115,14 +114,11 @@ def run_proxy(traffic, *, impl: str, batched: bool, tls, policy: bool,
         frames = [build_message(m, p) for m, p in msgs]
         src.deliver(src.tls.seal_frames(frames, src.parser.inner) if tls
                     else np.concatenate(frames))
-    t0 = time.perf_counter()
     rt.run()
-    stack.pool.block_until_ready()
-    wall_s = time.perf_counter() - t0
     wires = [d.tls.open_wire(d.tx_wire()) if tls else d.tx_wire()
              for d in backends]
     res = dict(wires=wires, messages=rt.messages_forwarded(),
-               rounds=rt.rounds, wall_s=wall_s,
+               rounds=rt.rounds,
                fused_rounds=stack.pool.xfer["fused_rounds"],
                tx_spec_hits=stack.pool.xfer["tx_spec_hits"],
                device_fallbacks=stack.counters.device_fallbacks)
@@ -157,8 +153,7 @@ def check_phase(name: str, traffic, *, impl: str, tls, policy: bool,
         raise AssertionError(f"{name}: {got['device_fallbacks']} rounds "
                              f"fell back off the device")
     line = {k: got[k] for k in ("messages", "rounds", "fused_rounds",
-                                "tx_spec_hits", "device_fallbacks",
-                                "wall_s")}
+                                "tx_spec_hits", "device_fallbacks")}
     if compiles is not None:
         line.update({k: compiles[k] - before.get(k, 0) for k in compiles})
     return line

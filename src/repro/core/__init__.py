@@ -48,6 +48,9 @@ multiplexes N flows with mixed parser policies over one stack.
                        injection (EAGAIN storms, resets, pool pressure,
                        worker kills, frame corruption) for testing the
                        fault-tolerance layer
+* ``trace``          — the datapath's per-round host spans
+                       (``jax.profiler.TraceAnnotation``; docs/API.md
+                       "Tracing")
 
 The free functions ``libra_recv``/``libra_send``/``libra_close``/
 ``expire_teardowns`` remain exported as the explicit-plumbing compatibility

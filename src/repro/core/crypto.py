@@ -64,6 +64,7 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.parser import (
     DEFAULT_LOOKAHEAD,
     LengthPrefixedParser,
@@ -142,18 +143,19 @@ def keystream_batch(keys: Sequence[bytes], seqs: Sequence[int],
     total = int(lens_arr.sum())
     if total == 0:
         return [np.zeros((0,), np.int64) for _ in lens]
-    seeds = np.array([_record_seed(k, s) for k, s in zip(keys, seqs)],
-                     np.uint64)
-    if offsets is not None:
-        seeds = seeds + np.asarray(offsets, np.uint64)
-    starts = np.zeros_like(lens_arr)
-    np.cumsum(lens_arr[:-1], out=starts[1:])
-    rel = np.arange(total, dtype=np.uint64) \
-        - np.repeat(starts.astype(np.uint64), lens_arr)
-    idx = rel + np.repeat(seeds, lens_arr)
-    ks = ((_splitmix64(idx) >> np.uint64(33)) & np.uint64(KS_MASK)
-          ).astype(np.int64)
-    return np.split(ks, np.cumsum(lens_arr)[:-1])
+    with trace.span("tls.keystream"):
+        seeds = np.array([_record_seed(k, s) for k, s in zip(keys, seqs)],
+                         np.uint64)
+        if offsets is not None:
+            seeds = seeds + np.asarray(offsets, np.uint64)
+        starts = np.zeros_like(lens_arr)
+        np.cumsum(lens_arr[:-1], out=starts[1:])
+        rel = np.arange(total, dtype=np.uint64) \
+            - np.repeat(starts.astype(np.uint64), lens_arr)
+        idx = rel + np.repeat(seeds, lens_arr)
+        ks = ((_splitmix64(idx) >> np.uint64(33)) & np.uint64(KS_MASK)
+              ).astype(np.int64)
+        return np.split(ks, np.cumsum(lens_arr)[:-1])
 
 
 def xor_tokens(tokens: np.ndarray, ks: np.ndarray) -> np.ndarray:

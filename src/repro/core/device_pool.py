@@ -38,6 +38,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.anchor_pool import AnchorPool, PageRef
 from repro.core.stream import TokenPool
 
@@ -258,21 +259,22 @@ class DevicePool(TokenPool):
         (TX-encrypted when ``tx_keystream`` is supplied)."""
         from repro.kernels import ops
 
-        self._ensure_device()
-        rows = np.unique(tables[tables >= 0]).astype(np.int64)
-        self._upload_rows(rows)               # may raise DeviceRangeError
-        self.xfer["h2d_tokens"] += stream.size + tables.size \
-            + meta_len.size + total_len.size \
-            + sum(op.size for op in (keystream, tx_keystream, cond_off,
-                                     cond_lo, cond_hi, live, meta_ks)
-                  if op is not None)
-        donated_in = self._dev
-        new_meta, new_pool, verdict, gathered = ops.fused_round(
-            stream, meta_len, total_len, self._dev, tables,
-            meta_max=meta_max, impl=impl, keystream=keystream,
-            tx_keystream=tx_keystream, cond_off=cond_off, cond_lo=cond_lo,
-            cond_hi=cond_hi, live=live, meta_ks=meta_ks,
-            n_buffers=n_buffers, donate_pool=True)
+        with trace.span("pool.call"):
+            self._ensure_device()
+            rows = np.unique(tables[tables >= 0]).astype(np.int64)
+            self._upload_rows(rows)           # may raise DeviceRangeError
+            self.xfer["h2d_tokens"] += stream.size + tables.size \
+                + meta_len.size + total_len.size \
+                + sum(op.size for op in (keystream, tx_keystream, cond_off,
+                                         cond_lo, cond_hi, live, meta_ks)
+                      if op is not None)
+            donated_in = self._dev
+            new_meta, new_pool, verdict, gathered = ops.fused_round(
+                stream, meta_len, total_len, self._dev, tables,
+                meta_max=meta_max, impl=impl, keystream=keystream,
+                tx_keystream=tx_keystream, cond_off=cond_off,
+                cond_lo=cond_lo, cond_hi=cond_hi, live=live,
+                meta_ks=meta_ks, n_buffers=n_buffers, donate_pool=True)
         del new_meta  # host buffers keep the int64-exact metadata
         self._dev = new_pool
         try:
@@ -284,13 +286,17 @@ class DevicePool(TokenPool):
         self.xfer["device_rounds"] += 1
         self.xfer["anchor_rounds"] += 1
         self.xfer["fused_rounds"] += 1
-        host_out = np.asarray(gathered)
+        # the gathered block's transfer waits for the kernel to finish
+        with trace.span("pool.wait"):
+            host_out = np.asarray(gathered)
+            host_verdict = None
+            if verdict is not None:
+                host_verdict = np.asarray(verdict)
         self.xfer["d2h_tokens"] += host_out.size
-        host_verdict = None
-        if verdict is not None:
-            host_verdict = np.asarray(verdict)
+        if host_verdict is not None:
             self.xfer["d2h_tokens"] += host_verdict.size
-        return host_verdict, host_out.astype(np.int64)
+        with trace.span("pool.widen"):
+            return host_verdict, host_out.astype(np.int64)
 
     def gather_batch_device(self, tables: np.ndarray, lengths: np.ndarray, *,
                             impl: str,
@@ -301,14 +307,17 @@ class DevicePool(TokenPool):
         bytes that are leaving on the wire anyway."""
         from repro.kernels import ops
 
-        self._ensure_device()
-        rows = np.unique(tables[tables >= 0]).astype(np.int64)
-        self._upload_rows(rows)               # may raise DeviceRangeError
-        self.xfer["h2d_tokens"] += tables.size + lengths.size \
-            + (keystream.size if keystream is not None else 0)
-        out = ops.selective_gather(self._dev, tables, lengths, impl=impl,
-                                   keystream=keystream)
-        host = np.asarray(out)
+        with trace.span("pool.call"):
+            self._ensure_device()
+            rows = np.unique(tables[tables >= 0]).astype(np.int64)
+            self._upload_rows(rows)           # may raise DeviceRangeError
+            self.xfer["h2d_tokens"] += tables.size + lengths.size \
+                + (keystream.size if keystream is not None else 0)
+            out = ops.selective_gather(self._dev, tables, lengths,
+                                       impl=impl, keystream=keystream)
+        with trace.span("pool.wait"):
+            host = np.asarray(out)
         self.xfer["d2h_tokens"] += host.size
         self.xfer["device_rounds"] += 1
-        return host.astype(np.int64)
+        with trace.span("pool.widen"):
+            return host.astype(np.int64)

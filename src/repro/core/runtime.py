@@ -44,6 +44,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.crypto import RecordAuthError
 from repro.core.policy import PUNT_BAD_BACKEND, Verdict
 from repro.core.socket import Events, LibraSocket
@@ -669,32 +670,34 @@ class ProxyRuntime:
         already serviced this round; ``ready`` supplies a ready set the
         caller already polled (ClusterRuntime), so channels are not
         readiness-evaluated twice per round."""
-        if ready is None:
-            ready = self.poll(skip)
-        progressed = (self._step_batched(ready) if self.batched
-                      else self._step_scalar(ready))
-        if progressed == 0:
-            # liveness: if backpressure alone paused the remaining work and
-            # nothing else can free pool pages, admit the paused channels —
-            # worst case they overflow into §A.1 drain, exactly as without
-            # backpressure
-            for ch in self.channels:
-                if ch._bp_paused and (skip is None or ch not in skip):
-                    ch._bp_paused = False
-                    progressed += bool(ch.service())
-        self.rounds += 1
-        self._rr += 1
-        if self.tick_every and self.rounds % self.tick_every == 0:
-            self.stack.tick()
-            h = getattr(self.policy, "health", None) \
-                if self.policy is not None else None
-            if h is not None:
-                # advance the circuit-breaker clock with the stack's: due
-                # UNHEALTHY backends move to HALF_OPEN (probe allowed)
-                h.tick(self.stack.now_tick)
-        if self.fault_plan is not None:
-            self.fault_plan.on_tick(self)
-        return progressed
+        with trace.span("runtime.step"):
+            if ready is None:
+                ready = self.poll(skip)
+            progressed = (self._step_batched(ready) if self.batched
+                          else self._step_scalar(ready))
+            if progressed == 0:
+                # liveness: if backpressure alone paused the remaining work
+                # and nothing else can free pool pages, admit the paused
+                # channels — worst case they overflow into §A.1 drain,
+                # exactly as without backpressure
+                for ch in self.channels:
+                    if ch._bp_paused and (skip is None or ch not in skip):
+                        ch._bp_paused = False
+                        progressed += bool(ch.service())
+            self.rounds += 1
+            self._rr += 1
+            if self.tick_every and self.rounds % self.tick_every == 0:
+                self.stack.tick()
+                h = getattr(self.policy, "health", None) \
+                    if self.policy is not None else None
+                if h is not None:
+                    # advance the circuit-breaker clock with the stack's:
+                    # due UNHEALTHY backends move to HALF_OPEN (probe
+                    # allowed)
+                    h.tick(self.stack.now_tick)
+            if self.fault_plan is not None:
+                self.fault_plan.on_tick(self)
+            return progressed
 
     def _step_scalar(self, ready) -> int:
         if self.scheduler == "drr":

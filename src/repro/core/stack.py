@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from repro.core import trace
 from repro.core.anchor_pool import AnchorPool, PageRef
 from repro.core.crypto import (
     REC_HEADER,
@@ -293,6 +294,37 @@ class LibraStack:
                 return buf_len.get(sock.fileno(), 1 << 20)
             return buf_len
 
+        with trace.span("stack.recv_batch"):
+            with trace.span("rx.admit"):
+                cands = self._admissible(socks, _bl)
+                if not cands:
+                    return {}
+                # ONE freelist pass allocates the whole round (placement
+                # identical to per-item alloc_sequence calls, so the pool
+                # layout — and every downstream byte — matches the scalar
+                # schedule exactly)
+                with plane_lock(self.alloc):
+                    page_lists = self.alloc.alloc_batch(
+                        [parsed.payload_len for _, parsed, _ in cands])
+            # every page list the round still owns, keyed by identity:
+            # entries leave as they are freed in-band (reject/overflow) or
+            # handed off to the registry; a fault anywhere below hands the
+            # rest back (OWN001)
+            round_owned = {id(pl): pl for pl in page_lists if pl is not None}
+            try:
+                return self._recv_batch_round(cands, page_lists, round_owned,
+                                              policy, impl, tx_hints)
+            except BaseException:
+                if round_owned:
+                    with plane_lock(self.alloc):
+                        self.alloc.free_batch(list(round_owned.values()))
+                raise
+
+    def _admissible(self, socks: Sequence[LibraSocket], buf_len_of
+                    ) -> List[Tuple[LibraSocket, object, int]]:
+        """The round's candidates ``(sock, parsed, buf_len)``: every socket
+        whose next frame the selective batch may take whole
+        (``buf_len_of(sock)`` is the socket's user buffer size)."""
         cands: List[Tuple[LibraSocket, object, int]] = []
         for sock in socks:
             conn = sock.connection
@@ -315,7 +347,7 @@ class LibraStack:
                 continue  # full-copy / unparseable: scalar path
             if conn.rx_available() < parsed.meta_len + parsed.payload_len:
                 continue  # NIC DMA incomplete: never anchor holes
-            bl = _bl(sock)
+            bl = buf_len_of(sock)
             if bl < parsed.meta_len + parsed.payload_len:
                 # the WHOLE logical message must fit the user buffer: a
                 # buf_len-capped round would hand back a truncated logical
@@ -325,122 +357,110 @@ class LibraStack:
                 # complete messages (every result below is machine-complete)
                 continue
             cands.append((sock, parsed, bl))
-        if not cands:
-            return {}
-
-        # ONE freelist pass allocates the whole round (placement identical
-        # to per-item alloc_sequence calls, so the pool layout — and every
-        # downstream byte — matches the scalar schedule exactly)
-        with plane_lock(self.alloc):
-            page_lists = self.alloc.alloc_batch(
-                [parsed.payload_len for _, parsed, _ in cands])
-        # every page list the round still owns, keyed by identity: entries
-        # leave as they are freed in-band (reject/overflow) or handed off to
-        # the registry; a fault anywhere below hands the rest back (OWN001)
-        round_owned = {id(pl): pl for pl in page_lists if pl is not None}
-        try:
-            return self._recv_batch_round(cands, page_lists, round_owned,
-                                          policy, impl, tx_hints)
-        except BaseException:
-            if round_owned:
-                with plane_lock(self.alloc):
-                    self.alloc.free_batch(list(round_owned.values()))
-            raise
+        return cands
 
     def _recv_batch_round(self, cands, page_lists, round_owned, policy,
                           impl, tx_hints=None
                           ) -> Dict[int, Tuple[np.ndarray, int]]:
-        items: List[_BatchItem] = []
-        leaked: List[List[PageRef]] = []
-        for (sock, parsed, bl), pages in zip(cands, page_lists):
-            if pages is None:
-                continue  # §A.1 overflow is the scalar path's business
-            sm = sock.connection.rx_machine
-            # drive the existing state machine: DEFAULT -> ... -> WRITE_VPI
-            decision = sm.on_recv(sock.connection.rx_window(sm.parser.lookahead),
-                                  bl, parsed=parsed)
-            if decision.state is not St.WRITE_VPI:
-                # should be unreachable given the admission checks above,
-                # but a machine that lands anywhere else must not leak the
-                # pages we just allocated: hand everything back and let the
-                # scalar path re-evaluate the socket from a clean state
-                # (nothing has been consumed from the ring yet)
-                leaked.append(pages)
-                sm.reset()
-                continue
-            items.append(_BatchItem(sock, bl, decision.copy_meta,
-                                    sm.payload_len, pages))
-        if leaked:
-            with plane_lock(self.alloc):
-                self.alloc.free_batch(leaked)
-            for pl in leaked:
-                round_owned.pop(id(pl), None)
-        if not items:
-            return {}
-
-        # -- selective copy of metadata (host buffers stay int64-exact) -----
-        crypt: List[_BatchItem] = []
-        for it in items:
-            conn = it.sock.connection
-            it.meta = conn.rx_peek(it.meta_len).copy()
-            conn.rx_advance(it.meta_len)
-            self.counters.meta_copied += it.meta_len
-            it.payload = conn.rx_peek(it.payload_len)
-            if conn.crypto is not None:
-                crypt.append(it)
-        if crypt:
-            # hw-kTLS (sw never reaches the batch): ONE vectorized keystream
-            # sweep covers every encrypted record of the round, inner
-            # metadata + payload. The metadata span decrypts right here
-            # (those bytes are being copied to user space anyway); the
-            # payload span is fused into the batched anchoring pass below —
-            # no per-message crypto work survives in the fused round.
-            kss = keystream_batch(
-                [it.sock.connection.crypto.rx_key for it in crypt],
-                [int(it.meta[1]) for it in crypt],
-                [it.meta_len - REC_HEADER + it.payload_len for it in crypt])
-            rejected = set()
-            for it, ks in zip(crypt, kss):
-                imeta = it.meta_len - REC_HEADER
-                crypto = it.sock.connection.crypto
-                if policy is not None:
-                    # keep the ciphertext inner metadata + its keystream
-                    # span: the device match pass consumes them as the
-                    # kernel's keystream operand (fused decrypt-and-match)
-                    it.cmeta = it.meta.copy()
-                    it.meta_ks = ks[:imeta]
-                it.meta[REC_HEADER:] = np.bitwise_xor(it.meta[REC_HEADER:],
-                                                      ks[:imeta])
-                it.ks = ks[imeta:]
-                # per-record auth, folded into this same sweep (the NIC
-                # verifies while it DMAs): a tag mismatch rejects the
-                # record before the fused anchoring pass — pages back to
-                # the freelist, record consumed, nothing charged, nothing
-                # delivered (scalar ``recv`` raises RecordAuthError for
-                # the same wire bytes; the batch drops the slot so one
-                # tampered flow cannot poison the round). The plaintext
-                # the check produces is kept: the host scatter anchors it
-                # directly (one cipher pass total); the device plane still
-                # ships ciphertext + keystream operands (the kernel's XOR
-                # is its fused decrypt).
-                it.plain = np.bitwise_xor(it.payload, it.ks)
-                if not crypto.verify_record(
-                        int(it.meta[1]), it.meta[TAG_SLOT],
-                        np.concatenate([it.meta[REC_HEADER:], it.plain])):
-                    self.counters.meta_copied -= it.meta_len
-                    with plane_lock(self.alloc):
-                        self.alloc.free_batch([it.pages])
-                    round_owned.pop(id(it.pages), None)
-                    it.sock.connection.rx_advance(it.payload_len)
-                    it.sock.connection.rx_machine.reset()
-                    it.sock._auth_rejected = True
-                    rejected.add(id(it))
+        with trace.span("rx.admit"):
+            items: List[_BatchItem] = []
+            leaked: List[List[PageRef]] = []
+            for (sock, parsed, bl), pages in zip(cands, page_lists):
+                if pages is None:
+                    continue  # §A.1 overflow is the scalar path's business
+                sm = sock.connection.rx_machine
+                # drive the existing state machine: DEFAULT -> ... ->
+                # WRITE_VPI
+                decision = sm.on_recv(
+                    sock.connection.rx_window(sm.parser.lookahead), bl,
+                    parsed=parsed)
+                if decision.state is not St.WRITE_VPI:
+                    # should be unreachable given the admission checks
+                    # above, but a machine that lands anywhere else must not
+                    # leak the pages we just allocated: hand everything back
+                    # and let the scalar path re-evaluate the socket from a
+                    # clean state (nothing has been consumed from the ring
+                    # yet)
+                    leaked.append(pages)
+                    sm.reset()
                     continue
-                crypto.stats["records_opened"] += 1
-            if rejected:
-                items = [it for it in items if id(it) not in rejected]
-                if not items:
-                    return {}
+                items.append(_BatchItem(sock, bl, decision.copy_meta,
+                                        sm.payload_len, pages))
+            if leaked:
+                with plane_lock(self.alloc):
+                    self.alloc.free_batch(leaked)
+                for pl in leaked:
+                    round_owned.pop(id(pl), None)
+            if not items:
+                return {}
+
+            # -- selective copy of metadata (host buffers stay int64-exact) -
+            crypt: List[_BatchItem] = []
+            for it in items:
+                conn = it.sock.connection
+                it.meta = conn.rx_peek(it.meta_len).copy()
+                conn.rx_advance(it.meta_len)
+                self.counters.meta_copied += it.meta_len
+                it.payload = conn.rx_peek(it.payload_len)
+                if conn.crypto is not None:
+                    crypt.append(it)
+
+        if crypt:
+            with trace.span("tls.rx_open"):
+                # hw-kTLS (sw never reaches the batch): ONE vectorized
+                # keystream sweep covers every encrypted record of the
+                # round, inner metadata + payload. The metadata span
+                # decrypts right here (those bytes are being copied to user
+                # space anyway); the payload span is fused into the batched
+                # anchoring pass below — no per-message crypto work
+                # survives in the fused round.
+                kss = keystream_batch(
+                    [it.sock.connection.crypto.rx_key for it in crypt],
+                    [int(it.meta[1]) for it in crypt],
+                    [it.meta_len - REC_HEADER + it.payload_len
+                     for it in crypt])
+                rejected = set()
+                for it, ks in zip(crypt, kss):
+                    imeta = it.meta_len - REC_HEADER
+                    crypto = it.sock.connection.crypto
+                    if policy is not None:
+                        # keep the ciphertext inner metadata + its keystream
+                        # span: the device match pass consumes them as the
+                        # kernel's keystream operand (fused decrypt-and-match)
+                        it.cmeta = it.meta.copy()
+                        it.meta_ks = ks[:imeta]
+                    it.meta[REC_HEADER:] = np.bitwise_xor(it.meta[REC_HEADER:],
+                                                          ks[:imeta])
+                    it.ks = ks[imeta:]
+                    # per-record auth, folded into this same sweep (the NIC
+                    # verifies while it DMAs): a tag mismatch rejects the
+                    # record before the fused anchoring pass — pages back to
+                    # the freelist, record consumed, nothing charged, nothing
+                    # delivered (scalar ``recv`` raises RecordAuthError for
+                    # the same wire bytes; the batch drops the slot so one
+                    # tampered flow cannot poison the round). The plaintext
+                    # the check produces is kept: the host scatter anchors it
+                    # directly (one cipher pass total); the device plane still
+                    # ships ciphertext + keystream operands (the kernel's XOR
+                    # is its fused decrypt).
+                    it.plain = np.bitwise_xor(it.payload, it.ks)
+                    if not crypto.verify_record(
+                            int(it.meta[1]), it.meta[TAG_SLOT],
+                            np.concatenate([it.meta[REC_HEADER:], it.plain])):
+                        self.counters.meta_copied -= it.meta_len
+                        with plane_lock(self.alloc):
+                            self.alloc.free_batch([it.pages])
+                        round_owned.pop(id(it.pages), None)
+                        it.sock.connection.rx_advance(it.payload_len)
+                        it.sock.connection.rx_machine.reset()
+                        it.sock._auth_rejected = True
+                        rejected.add(id(it))
+                        continue
+                    crypto.stats["records_opened"] += 1
+                if rejected:
+                    items = [it for it in items if id(it) not in rejected]
+                    if not items:
+                        return {}
 
         # -- one-kernel round: anchor + decrypt + match + gather, 1 launch --
         base = _fused_base(impl)
@@ -484,38 +504,39 @@ class LibraStack:
         """The round's per-socket bookkeeping tail, shared by the fused and
         multi-pass data planes: register each anchor, advance the RX
         machine, and hand back the ``[meta..., VPI]`` user buffers."""
-        results: Dict[int, Tuple[np.ndarray, int]] = {}
-        for it in items:
-            conn = it.sock.connection
-            sm = conn.rx_machine
-            self.counters.anchored += it.payload_len
-            self.counters.allocs += 1
-            conn.rx_advance(it.payload_len)
-            with plane_lock(self.registry):
-                vpi = self.registry.register(
-                    self.pool.pool_id,
-                    [(p.shard, p.local_pid, p.base_pos) for p in it.pages],
-                    it.payload_len,
-                )
-            round_owned.pop(id(it.pages), None)
-            conn.anchored[vpi] = (it.pages, it.payload_len)
-            buf = np.concatenate(
-                [it.meta, np.array([VpiRegistry.to_token(vpi)], np.int64)])
-            self.counters.vpi_injected += 1
-            # admission guaranteed logical room for the whole message, so
-            # the credit always completes the machine (scalar ``recv`` owns
-            # buf_len-truncated logical delivery)
-            logical = it.meta_len + it.payload_len
-            sm.on_payload_consumed(it.payload_len)
-            self._note_anchor_owner(it.sock)
-            # park (or clear) the fused round's speculative TX descriptor:
-            # unconditional, so a stale guess from an earlier round can
-            # never alias a recycled VPI
-            if it.fused_tx is not None:
-                it.fused_tx["vpi"] = vpi
-            it.sock._fused_tx = it.fused_tx
-            results[it.sock.fileno()] = (buf, logical)
-        return results
+        with trace.span("rx.scatter"):
+            results: Dict[int, Tuple[np.ndarray, int]] = {}
+            for it in items:
+                conn = it.sock.connection
+                sm = conn.rx_machine
+                self.counters.anchored += it.payload_len
+                self.counters.allocs += 1
+                conn.rx_advance(it.payload_len)
+                with plane_lock(self.registry):
+                    vpi = self.registry.register(
+                        self.pool.pool_id,
+                        [(p.shard, p.local_pid, p.base_pos) for p in it.pages],
+                        it.payload_len,
+                    )
+                round_owned.pop(id(it.pages), None)
+                conn.anchored[vpi] = (it.pages, it.payload_len)
+                buf = np.concatenate(
+                    [it.meta, np.array([VpiRegistry.to_token(vpi)], np.int64)])
+                self.counters.vpi_injected += 1
+                # admission guaranteed logical room for the whole message, so
+                # the credit always completes the machine (scalar ``recv`` owns
+                # buf_len-truncated logical delivery)
+                logical = it.meta_len + it.payload_len
+                sm.on_payload_consumed(it.payload_len)
+                self._note_anchor_owner(it.sock)
+                # park (or clear) the fused round's speculative TX descriptor:
+                # unconditional, so a stale guess from an earlier round can
+                # never alias a recycled VPI
+                if it.fused_tx is not None:
+                    it.fused_tx["vpi"] = vpi
+                it.sock._fused_tx = it.fused_tx
+                results[it.sock.fileno()] = (buf, logical)
+            return results
 
     def _policy_match_round(self, items: List[_BatchItem], policy,
                             impl: str) -> None:
@@ -694,18 +715,20 @@ class LibraStack:
         if not isinstance(self.pool, DevicePool):
             return False
         page = self.alloc.page_size
-        for it in items:
-            if not (_fits_int32(it.meta) and _fits_int32(it.payload)):
-                return False
-            if any(pg.base_pos != j * page
-                   for j, pg in enumerate(it.pages)):
-                # the in-register gather addresses payload position
-                # [j*page, (j+1)*page) through table slot j — only the
-                # allocator's contiguous layout qualifies
-                return False
-        stream, meta_len, total_len, tables, ks, meta_max = \
-            self._round_operands(items)
-        txks = self._speculate_tx(items, tx_hints, tables.shape[1] * page)
+        with trace.span("rx.stage"):
+            for it in items:
+                if not (_fits_int32(it.meta) and _fits_int32(it.payload)):
+                    return False
+                if any(pg.base_pos != j * page
+                       for j, pg in enumerate(it.pages)):
+                    # the in-register gather addresses payload position
+                    # [j*page, (j+1)*page) through table slot j — only the
+                    # allocator's contiguous layout qualifies
+                    return False
+            stream, meta_len, total_len, tables, ks, meta_max = \
+                self._round_operands(items)
+            txks = self._speculate_tx(items, tx_hints,
+                                      tables.shape[1] * page)
         off = lo = hi = live = None
         if policy is not None:
             off, lo, hi = policy.cond_off, policy.cond_lo, policy.cond_hi
@@ -722,8 +745,9 @@ class LibraStack:
             # the fused launch IS this round's match pass; resolution stays
             # host-side exactly as in _policy_match_round
             policy.stats["rounds"] += 1
-            pmetas, mlens = self._round_meta_block(items)
-            self._park_verdicts(items, policy, verdict, pmetas, mlens)
+            with trace.span("rx.verdicts"):
+                pmetas, mlens = self._round_meta_block(items)
+                self._park_verdicts(items, policy, verdict, pmetas, mlens)
         for i, it in enumerate(items):
             if it.fused_tx is not None:
                 it.fused_tx["payload"] = gathered[i, : it.payload_len]
@@ -868,15 +892,30 @@ class LibraStack:
         eligibility is decided, and the fused gathers are grouped by the
         pool that owns each entry's pages — a grant's payload is gathered
         straight off the owning worker's (device-resident) pool."""
-        sends = list(sends)
-        # under one-kernel rounds, sends the fused recv did not speculate
-        # (or whose guess missed) gather on the same underlying device impl
-        base = _fused_base(impl)
-        if base is not None:
-            impl = base
+        with trace.span("stack.forward_batch"):
+            sends = list(sends)
+            # under one-kernel rounds, sends the fused recv did not
+            # speculate (or whose guess missed) gather on the same
+            # underlying device impl
+            base = _fused_base(impl)
+            if base is not None:
+                impl = base
+            with trace.span("tx.prepare"):
+                prefetch, peeks, gather = self._forward_prepare(sends)
+            if gather:
+                self._forward_gather(sends, gather, prefetch, impl)
+            with trace.span("tx.transmit"):
+                return self._forward_transmit(sends, peeks, prefetch)
+
+    def _forward_prepare(self, sends: List[Tuple]):
+        """Peek each send's message (adopting cross-worker handles, which
+        rewrites ``sends[k]``), consume the fused round's speculative
+        payloads that validate, and list the rest for the gather. Returns
+        ``(prefetch, peeks, gather)``: the payload in hand per send, the
+        peek per send, and ``(send slot, entry, (pages, len), ksinfo)``
+        per send still to gather."""
         prefetch: List[Optional[np.ndarray]] = [None] * len(sends)
         peeks: List[Optional[Tuple]] = [None] * len(sends)
-        # (send slot, entry, (pages, len), ksinfo) per prefetch-eligible send
         gather: List[Tuple[int, object, Tuple, Optional[Tuple]]] = []
         for k, (src, dst, buf, budget) in enumerate(sends):
             if dst.pending_send is not None or dst.closed:
@@ -920,6 +959,7 @@ class LibraStack:
                     prefetch[k] = np.asarray(spec["payload"], np.int64)
                     self.pool.xfer["tx_spec_hits"] += 1
                     continue
+                self.pool.xfer["tx_spec_misses"] += 1
             ksinfo = None
             if crypto is not None:
                 # hw-kTLS: (session, seq, inner-meta length) — the whole
@@ -930,38 +970,52 @@ class LibraStack:
                 ksinfo = (crypto, int(buf64[1]), peek[0] - REC_HEADER)
             gather.append((k, entry, ([PageRef(*pg) for pg in entry.pages],
                                       entry.payload_len), ksinfo))
-        if gather:
-            keystreams: List[Optional[np.ndarray]] = [None] * len(gather)
-            enc = [(i, info) for i, (_, _, _, info) in enumerate(gather)
-                   if info is not None]
-            if enc:
-                kss = keystream_batch(
-                    [info[0].tx_key for _, info in enc],
-                    [info[1] for _, info in enc],
-                    [info[2] + gather[i][2][1] for i, info in enc])
-                for (i, (crypto, seq, imeta)), ks in zip(enc, kss):
-                    crypto.stash_tx_meta_ks(seq, ks[:imeta])
-                    keystreams[i] = ks[imeta:]
-            # one-copy stash entries carry their payload already; pool
-            # entries are gathered per owning pool (grants read the peer
-            # worker's pool, local anchors read ours) — one fused gather
-            # per pool touched by the round
-            groups: Dict[int, Tuple[TokenPool, List[int]]] = {}
-            for i, (k, entry, seq_info, _) in enumerate(gather):
-                if entry.stash is not None:
-                    pv = np.asarray(entry.stash, np.int64)
-                    if keystreams[i] is not None:
-                        pv = np.bitwise_xor(pv, keystreams[i])
-                    prefetch[k] = pv
-                    continue
-                owner = sends[k][1].stack.pool_for_entry(entry)
-                groups.setdefault(id(owner), (owner, []))[1].append(i)
-            for owner, idxs in groups.values():
-                payloads = self._gather_payloads(
-                    [gather[i][2] for i in idxs],
-                    [keystreams[i] for i in idxs], impl, pool=owner)
-                for i, pv in zip(idxs, payloads):
-                    prefetch[gather[i][0]] = pv
+        return prefetch, peeks, gather
+
+    def _forward_gather(self, sends: List[Tuple], gather: List[Tuple],
+                        prefetch: List[Optional[np.ndarray]],
+                        impl: str) -> None:
+        """Fetch the payloads of ``gather`` (from :meth:`_forward_prepare`)
+        into ``prefetch``: one keystream sweep for the hw-kTLS sends, then
+        one fused gather per pool the round touches."""
+        keystreams: List[Optional[np.ndarray]] = [None] * len(gather)
+        enc = [(i, info) for i, (_, _, _, info) in enumerate(gather)
+               if info is not None]
+        if enc:
+            kss = keystream_batch(
+                [info[0].tx_key for _, info in enc],
+                [info[1] for _, info in enc],
+                [info[2] + gather[i][2][1] for i, info in enc])
+            for (i, (crypto, seq, imeta)), ks in zip(enc, kss):
+                crypto.stash_tx_meta_ks(seq, ks[:imeta])
+                keystreams[i] = ks[imeta:]
+        # one-copy stash entries carry their payload already; pool
+        # entries are gathered per owning pool (grants read the peer
+        # worker's pool, local anchors read ours) — one fused gather
+        # per pool touched by the round
+        groups: Dict[int, Tuple[TokenPool, List[int]]] = {}
+        for i, (k, entry, seq_info, _) in enumerate(gather):
+            if entry.stash is not None:
+                pv = np.asarray(entry.stash, np.int64)
+                if keystreams[i] is not None:
+                    pv = np.bitwise_xor(pv, keystreams[i])
+                prefetch[k] = pv
+                continue
+            owner = sends[k][1].stack.pool_for_entry(entry)
+            groups.setdefault(id(owner), (owner, []))[1].append(i)
+        for owner, idxs in groups.values():
+            payloads = self._gather_payloads(
+                [gather[i][2] for i in idxs],
+                [keystreams[i] for i in idxs], impl, pool=owner)
+            for i, pv in zip(idxs, payloads):
+                prefetch[gather[i][0]] = pv
+
+    def _forward_transmit(self, sends: List[Tuple],
+                          peeks: List[Optional[Tuple]],
+                          prefetch: List[Optional[np.ndarray]]
+                          ) -> List[Tuple[str, int]]:
+        """Hand each send to its socket's normal transmit path, with the
+        payload in hand where the round fetched one."""
         out: List[Tuple[str, int]] = []
         for k, (src, dst, buf, budget) in enumerate(sends):
             peeked, pf = peeks[k], prefetch[k]
@@ -1030,16 +1084,17 @@ class LibraStack:
         page = pool.alloc.page_size
         b = len(seqs)
         pps = max((len(pages) for pages, _ in seqs), default=1) or 1
-        tables = np.full((b, pps), -1, np.int32)
-        lengths = np.zeros((b,), np.int32)
-        ks = (np.zeros((b, pps * page), np.int32)
-              if any(k is not None for k in keystreams) else None)
-        for i, (pages, ln) in enumerate(seqs):
-            lengths[i] = ln
-            for j, pg in enumerate(pages):
-                tables[i, j] = pool.alloc.flat_pid(pg)
-            if ks is not None and keystreams[i] is not None:
-                ks[i, :ln] = keystreams[i]
+        with trace.span("tx.stage"):
+            tables = np.full((b, pps), -1, np.int32)
+            lengths = np.zeros((b,), np.int32)
+            ks = (np.zeros((b, pps * page), np.int32)
+                  if any(k is not None for k in keystreams) else None)
+            for i, (pages, ln) in enumerate(seqs):
+                lengths[i] = ln
+                for j, pg in enumerate(pages):
+                    tables[i, j] = pool.alloc.flat_pid(pg)
+                if ks is not None and keystreams[i] is not None:
+                    ks[i, :ln] = keystreams[i]
         block = pool.gather_batch_device(tables, lengths, impl=impl,
                                          keystream=ks)
         return [block[i, :ln] for i, (_, ln) in enumerate(seqs)]

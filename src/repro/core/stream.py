@@ -158,8 +158,11 @@ class TokenPool:
                                      "policy_match_rounds": 0,
                                      # forward_batch consumed a fused
                                      # round's speculative TX gather
-                                     # output (no gather launch needed)
-                                     "tx_spec_hits": 0}
+                                     # output (no gather launch needed),
+                                     # or found the send's speculation
+                                     # wrong and gathered it again
+                                     "tx_spec_hits": 0,
+                                     "tx_spec_misses": 0}
 
     @property
     def data(self) -> np.ndarray:
