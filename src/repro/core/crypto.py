@@ -96,13 +96,18 @@ class RecordAuthError(Exception):
 # keystream (deterministic, vectorized, host/device-identical)
 # ---------------------------------------------------------------------------
 
-def _splitmix64(x: np.ndarray) -> np.ndarray:
-    """Vectorized splitmix64 finalizer over uint64 arrays (numpy array ops
-    wrap mod 2**64 silently; only scalar ops would warn)."""
-    z = x + np.uint64(0x9E3779B97F4A7C15)
-    z = (z ^ (z >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
-    z = (z ^ (z >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
-    return z ^ (z >> np.uint64(31))
+#: tokens per keystream block: a block's uint64 rows and its int64 output
+#: slice (about 1 MiB in all) stay in a core's L2 cache through every
+#: splitmix64 pass, where whole-sweep temporaries would stream each pass
+#: through freshly faulted memory
+KS_BLOCK = 1 << 15
+
+_U64 = np.uint64
+_SM_ADD = _U64(0x9E3779B97F4A7C15)
+_SM_MUL1 = _U64(0xBF58476D1CE4E5B9)
+_SM_MUL2 = _U64(0x94D049BB133111EB)
+_IOTA = np.arange(KS_BLOCK, dtype=_U64)
+_IOTA.flags.writeable = False
 
 
 @functools.lru_cache(maxsize=8192)
@@ -115,6 +120,55 @@ def _record_seed(key: bytes, seq: int) -> int:
                               digest_size=8).digest())[0]
 
 
+def _keystreams(keys: Sequence[bytes], seqs: Sequence[int],
+                lens: np.ndarray,
+                offsets: Optional[Sequence[int]]) -> np.ndarray:
+    """The keystreams of several records, concatenated in one int64 array:
+    token ``i`` of record ``r`` is the top 31 bits of splitmix64(record
+    seed + ``offsets[r]`` + ``i``).
+
+    Works one :data:`KS_BLOCK` at a time: the block's indices (each
+    record's base repeated over its tokens, plus the block's positions) go
+    into one of two reused uint64 scratch rows, and splitmix64 runs there
+    in place (``out=``). Every op is an array op, so indices and products
+    wrap mod 2**64 silently, as a scalar op would not."""
+    seeds = np.array([_record_seed(k, s) for k, s in zip(keys, seqs)], _U64)
+    if offsets is not None:
+        seeds += np.asarray(offsets, _U64)
+    ends = np.add.accumulate(lens)
+    starts = ends - lens
+    # index of the token at stream position p of record r: base[r] + p
+    base = seeds - starts.view(_U64)
+    total = int(ends[-1])
+    out = np.empty(total, np.int64)
+    # the tokens fit 31 bits, so they are written through a uint64 view
+    ov = out.view(_U64)
+    width = min(KS_BLOCK, total)
+    z = np.empty(width, _U64)
+    t = np.empty(width, _U64)
+    for b0 in range(0, total, width):
+        n = min(width, total - b0)
+        zb, tb = z[:n], t[:n]
+        # the records this block overlaps, and how many of its tokens each
+        r0 = int(starts.searchsorted(b0, "right")) - 1
+        r1 = int(starts.searchsorted(b0 + n, "left"))
+        seg = np.minimum(ends[r0:r1], b0 + n)
+        seg -= np.maximum(starts[r0:r1], b0)
+        np.add(np.repeat(base[r0:r1] + _U64(b0), seg), _IOTA[:n], out=zb)
+        # splitmix64 finalizer, then the top 31 bits (within KS_MASK)
+        np.add(zb, _SM_ADD, out=zb)
+        np.right_shift(zb, 30, out=tb)
+        np.bitwise_xor(zb, tb, out=zb)
+        np.multiply(zb, _SM_MUL1, out=zb)
+        np.right_shift(zb, 27, out=tb)
+        np.bitwise_xor(zb, tb, out=zb)
+        np.multiply(zb, _SM_MUL2, out=zb)
+        np.right_shift(zb, 31, out=tb)
+        np.bitwise_xor(zb, tb, out=zb)
+        np.right_shift(zb, 33, out=ov[b0 : b0 + n])
+    return out
+
+
 def keystream(key: bytes, seq: int, n: int, offset: int = 0) -> np.ndarray:
     """``n`` keystream tokens for record ``seq`` starting at encrypted-region
     position ``offset`` (position 0 = first token after the record header).
@@ -123,38 +177,25 @@ def keystream(key: bytes, seq: int, n: int, offset: int = 0) -> np.ndarray:
     at arbitrary offsets."""
     if n <= 0:
         return np.zeros((0,), np.int64)
-    seed = _record_seed(key, seq)
-    idx = np.arange(offset, offset + n, dtype=np.uint64) + np.uint64(seed)
-    return ((_splitmix64(idx) >> np.uint64(33)) & np.uint64(KS_MASK)
-            ).astype(np.int64)
+    return _keystreams([key], [seq], np.array([n], np.int64), [offset])
 
 
 def keystream_batch(keys: Sequence[bytes], seqs: Sequence[int],
                     lens: Sequence[int],
                     offsets: Optional[Sequence[int]] = None,
                     ) -> "list[np.ndarray]":
-    """Keystream spans for a whole batch of records in ONE vectorized pass
-    (one index build + one splitmix sweep over the concatenated lengths) —
+    """Keystream spans for a whole batch of records in ONE sweep over the
+    concatenated lengths, generated block by block (:data:`KS_BLOCK`) —
     the hw-mode batched data plane generates every record's keystream here,
     so per-message Python overhead stays out of the fused rounds. Returns
-    one array per (key, seq, len, offset) quadruple; equals per-record
-    :func:`keystream` calls token for token."""
+    one array per (key, seq, len, offset) quadruple, each a view into the
+    sweep's one output; equals per-record :func:`keystream` calls token
+    for token."""
     lens_arr = np.asarray(lens, np.int64)
-    total = int(lens_arr.sum())
-    if total == 0:
+    if int(lens_arr.sum()) == 0:
         return [np.zeros((0,), np.int64) for _ in lens]
     with trace.span("tls.keystream"):
-        seeds = np.array([_record_seed(k, s) for k, s in zip(keys, seqs)],
-                         np.uint64)
-        if offsets is not None:
-            seeds = seeds + np.asarray(offsets, np.uint64)
-        starts = np.zeros_like(lens_arr)
-        np.cumsum(lens_arr[:-1], out=starts[1:])
-        rel = np.arange(total, dtype=np.uint64) \
-            - np.repeat(starts.astype(np.uint64), lens_arr)
-        idx = rel + np.repeat(seeds, lens_arr)
-        ks = ((_splitmix64(idx) >> np.uint64(33)) & np.uint64(KS_MASK)
-              ).astype(np.int64)
+        ks = _keystreams(keys, seqs, lens_arr, offsets)
         return np.split(ks, np.cumsum(lens_arr)[:-1])
 
 

@@ -1,6 +1,11 @@
 """kTLS-analogue encrypted datapath: record layer, token cipher, sw/hw
 modes through the socket facade, batched crypto rounds, and the fused
 kernel's keystream operand."""
+import contextlib
+import hashlib
+import struct
+import tracemalloc
+
 import numpy as np
 import pytest
 
@@ -12,11 +17,14 @@ from repro.core import (
     build_chunked_message,
     build_delimited_message,
     build_message,
+    crypto,
     open_record,
     open_stream,
     seal_record,
+    trace,
 )
 from repro.core.crypto import (
+    KS_BLOCK,
     KS_MASK,
     REC_HEADER,
     REC_MAGIC,
@@ -70,6 +78,107 @@ def test_keystream_batch_matches_per_record_calls():
     batched = keystream_batch(keys, seqs, lens, offsets=offs)
     for got, k, s, n, o in zip(batched, keys, seqs, lens, offs):
         assert np.array_equal(got, keystream(k, s, n, o))
+
+
+_M64 = (1 << 64) - 1
+
+
+def _oracle_seed(key, seq):
+    """The record seed, straight from its definition (blake2b of seq)."""
+    return struct.unpack("<Q", hashlib.blake2b(
+        struct.pack("<q", seq), key=key, digest_size=8).digest())[0]
+
+
+def _oracle_keystream(seed, n, offset):
+    """Scalar splitmix64 in Python ints mod 2**64: token i is the top 31
+    bits of splitmix64(seed + offset + i)."""
+    toks = []
+    for i in range(n):
+        z = (seed + offset + i + 0x9E3779B97F4A7C15) & _M64
+        z = ((z ^ (z >> 30)) * 0xBF58476D1CE4E5B9) & _M64
+        z = ((z ^ (z >> 27)) * 0x94D049BB133111EB) & _M64
+        z ^= z >> 31
+        toks.append((z >> 33) & KS_MASK)
+    return np.array(toks, np.int64)
+
+
+#: (len, offset) per record of one sweep
+KAT_CASES = {
+    "longer-than-a-block": [(65536, 0)],
+    "block-boundaries": [(KS_BLOCK - 1, 0), (2, 5), (KS_BLOCK + 1, 0),
+                         (KS_BLOCK, 3), (KS_BLOCK - 1, 1)],
+    "zero-lengths": [(0, 0), (7, 0), (0, 4), (0, 0), (12, 1), (0, 9)],
+    "offsets": [(100, 1), (50, KS_BLOCK - 1), (33, 1 << 40)],
+}
+
+
+@pytest.mark.parametrize("wrap", [False, True], ids=["seeds", "wrap-2**64"])
+@pytest.mark.parametrize("case", sorted(KAT_CASES))
+def test_keystream_known_answers_against_scalar_splitmix(
+        monkeypatch, case, wrap):
+    """Both entry points against an independent scalar oracle; ``wrap``
+    puts every record seed within a few tokens of 2**64, so the indices
+    wrap mod 2**64 inside the records."""
+    records = KAT_CASES[case]
+    keys = [bytes([65 + i % 3]) * 16 for i in range(len(records))]
+    seqs = [11 + i for i in range(len(records))]
+    seed_of = _oracle_seed
+    if wrap:
+        def seed_of(key, seq):
+            return (_M64 - 2 - seq % 3) & _M64
+        monkeypatch.setattr(crypto, "_record_seed", seed_of)
+    lens = [n for n, _ in records]
+    offs = [o for _, o in records]
+    batched = keystream_batch(keys, seqs, lens, offsets=offs)
+    assert len(batched) == len(records)
+    for got, k, s, (n, o) in zip(batched, keys, seqs, records):
+        want = _oracle_keystream(seed_of(k, s), n, o)
+        assert got.dtype == np.int64
+        assert np.array_equal(got, want)
+        assert np.array_equal(keystream(k, s, n, o), want)
+
+
+def test_keystream_literal_vector():
+    """Tokens pinned from the whole-array implementation this one replaced."""
+    want = [329788551, 204235295, 653698113, 1584559891, 1441911209,
+            216831013, 2116752157, 1672633375, 1047021839, 658867411,
+            664264769, 1244399173]
+    assert keystream(b"k" * 16, 7, 12, 5).tolist() == want
+    got = keystream_batch([b"j" * 16, b"k" * 16], [7, 7], [3, 12],
+                          offsets=[0, 5])
+    assert got[1].tolist() == want
+
+
+def test_keystream_batch_peak_memory_is_its_output():
+    """A sweep holds the output and block-sized scratch, never a
+    temporary the size of the sweep (the whole-array sweep peaked at
+    several times its output)."""
+    lens = [16384] * 128
+    keystream_batch([b"w" * 16], [0], [KS_BLOCK + 1])   # warm imports
+    tracemalloc.start()
+    try:
+        kss = keystream_batch([b"m" * 16] * len(lens), range(len(lens)), lens)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert [len(k) for k in kss] == lens
+    assert peak <= 8 * sum(lens) + 4 * 2 ** 20, peak
+
+
+def test_keystream_sweep_opens_one_span_however_many_blocks(monkeypatch):
+    names = []
+
+    @contextlib.contextmanager
+    def span(name):
+        names.append(name)
+        yield
+
+    monkeypatch.setattr(trace, "span", span)
+    keystream_batch([b"t" * 16] * 3, [1, 2, 3], [KS_BLOCK] * 3)
+    assert names == ["tls.keystream"]
+    keystream(b"t" * 16, 1, 3 * KS_BLOCK)
+    keystream_batch([b"t" * 16], [1], [0])
+    assert names == ["tls.keystream"]
 
 
 def test_xor_cipher_is_involution_and_int32_safe():
