@@ -4,7 +4,8 @@ against the reference, and the result line.
 The entry points (``run.py``, ``control.py``) look for the chip with
 :func:`check_chip` first; the tests drive :func:`run` on the CPU with a
 small configuration. ``run.py`` prints a result only where every round of
-the window took the fused device round (:func:`finish`).
+the window took the fused device round and nothing compiled inside the
+window (:func:`finish`): either is a window off the path the cell times.
 """
 from __future__ import annotations
 
@@ -20,7 +21,7 @@ from typing import Callable, Dict, Optional
 
 from chipbench import reference, refcipher, spec
 from chipbench import trace as tracing
-from chipbench.loop import ClosedLoop, Window
+from chipbench.loop import ClosedLoop, OpenLoop, Window
 from chipbench.proxy import Proxy, build
 from chipbench.traffic import Traffic
 
@@ -165,7 +166,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         traffic.seal([refcipher.session_key(secret, b"tls-rx", c.fileno())
                       for c in proxy.clients])
     marks["seal"] = time.perf_counter() - t_start
-    loop = ClosedLoop(proxy, traffic)
+    loop = (ClosedLoop if traffic.arrivals is None else OpenLoop)(
+        proxy, traffic)
     c_warm = counter.compiles
     loop.warmup(int(cell.mix["warmup_rounds"]))
     proxy.stack.pool.block_until_ready()
@@ -194,12 +196,13 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
             w = loop.window(seconds)
     finally:
         gc.callbacks.remove(gc_clock)
+    compiles_in_window = counter.compiles - c0
     xfer = {k: v - xfer0.get(k, 0) for k, v in proxy.stack.pool.xfer.items()}
     path = {"steps": len(w.steps),
             "fused_rounds": xfer.get("fused_rounds", 0),
             "device_fallbacks":
-                proxy.stack.counters.device_fallbacks - fallbacks0}
-    compiles_in_window = counter.compiles - c0
+                proxy.stack.counters.device_fallbacks - fallbacks0,
+            "compiles_in_window": compiles_in_window}
     trace = None
     if traced:
         tracing.stop()
@@ -217,13 +220,25 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
          f"{gc_clock.count}, {gc_clock.total_s * 1e3:.1f} ms in all, "
          f"longest {gc_clock.longest_s * 1e3:.1f} ms")
     _log("step ms: " + " ".join(f"{(e - s) * 1e3:.1f}" for s, e in w.steps))
+    if isinstance(loop, OpenLoop):
+        a = cell.mix["arrival"]
+        _log(f"open loop: offered {w.attempted / w.seconds:.3f} msgs/s in "
+             f"the window, schedule {a['rate_msgs_per_s']} msgs/s (on "
+             f"{a['on_s']} s, off {a['off_s']} s)")
     if w.ran_out:
-        _log(f"the {traffic.rounds} rounds set-up made ran out after "
-             f"{w.seconds:.3f} s of the window's {seconds} s and closed it: "
-             f"raise the mix's max_rounds_per_s")
+        _log(f"the requests set-up made ran out after {w.seconds:.3f} s of "
+             f"the window's {seconds} s and closed it: raise the mix's "
+             + ("max_rounds_per_s" if traffic.arrivals is None
+                else "warmup_s, or the schedule's margin"))
 
     # -- after the window: late requests, shutdown, the check -----------------
+    t_drain, c_drain, n_late = time.perf_counter(), counter.compiles, \
+        len(w.latencies_s)
     loop.drain(w, LATE_S)
+    _log(f"after the window: {len(w.latencies_s) - n_late} late requests "
+         f"forwarded in {time.perf_counter() - t_drain:.3f} s, compiles "
+         f"{counter.compiles - c_drain}, {loop.outstanding()} left "
+         f"outstanding")
     proxy.runtime.shutdown()
     keys = _keys(proxy, secret) if tls else None
     numbers, failed = reference.check(
@@ -259,9 +274,11 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
 
 
 def off_path(path: dict) -> Optional[str]:
-    """Why the window's rounds did not all take the fused device round the
-    cell measures, or None where they did: one fused round per step, and
-    no round bounced to the program's host or multi-pass paths."""
+    """Why the window is not the path the cell times, or None where it
+    is: nothing compiled inside it, one fused round per step, and no round
+    bounced to the program's host or multi-pass paths."""
+    if path["compiles_in_window"]:
+        return f"{path['compiles_in_window']} compiles in the window"
     if path["device_fallbacks"]:
         return f"{path['device_fallbacks']} rounds fell back off the device"
     if path["fused_rounds"] != path["steps"]:
@@ -281,11 +298,11 @@ def emit(line: dict) -> None:
 
 def finish(line: dict) -> int:
     """The exit code of a run: 0 with the result emitted; non-zero, with
-    no result, where the window left the fused device round."""
+    no result, where the window left the fused device round or compiled."""
     why = off_path(line["path"])
     if why is None:
         emit(line)
         return 0
-    _log(f"chipbench: the window left the fused device round ({why}); "
-         f"no result")
+    _log(f"chipbench: the window left the fused device round or compiled "
+         f"({why}); no result")
     return 4

@@ -7,14 +7,15 @@ The cell, its configuration and its traffic mix are found by name through
 ``BENCHMARK.json`` at the checkout's root. Set-up builds the proxy and the
 seeded traffic and serves the warm-up rounds (every shape the window uses
 compiles there, into JAX's persistent cache at ``<checkout>/.jax_cache``);
-then the closed loop runs for ``--seconds`` and every request is checked
-against the plain reference. ``--trace 0`` reports the cell's end-to-end
-metrics, ``--trace 1`` its per-layer metrics from a profiler trace of the
-window. The last line of standard output is one JSON object; the numbers
-the check compared, each beside its limit, are the last lines of standard
-error. Exits non-zero, printing no result, where JAX finds no TPU or fewer
-chips than the cell asks for, or where a round of the window left the fused
-device round.
+then the mix's loop, closed or open (``chipbench/loop.py``), runs for
+``--seconds`` and every request is checked against the plain reference.
+``--trace 0`` reports the cell's end-to-end metrics, ``--trace 1`` its
+per-layer metrics from a profiler trace of the window. The last line of
+standard output is one JSON object; the numbers the check compared, each
+beside its limit, are the last lines of standard error. Exits non-zero,
+printing no result, where JAX finds no TPU or fewer chips than the cell
+asks for, where a round of the window left the fused device round, or
+where anything compiled inside the window.
 """
 import time
 
@@ -43,7 +44,7 @@ def main(argv=None) -> int:
 
     try:
         cell = spec.cell(args.workload)
-    except (FileNotFoundError, KeyError) as e:
+    except (FileNotFoundError, KeyError, ValueError) as e:
         print(f"chipbench: no cell {args.workload!r}: {e}", file=sys.stderr)
         return 2
     # the compile cache lives in this checkout, whatever the environment says

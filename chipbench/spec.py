@@ -13,6 +13,7 @@ from typing import Callable, Dict, List, Optional
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
+ARRIVAL_KEYS = ("kind", "rate_msgs_per_s", "warmup_s", "on_s", "off_s")
 
 
 @dataclasses.dataclass
@@ -34,16 +35,48 @@ def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def check_arrival(mix: dict) -> None:
+    """Raise ``ValueError`` where the mix's ``arrival`` is not one the
+    traffic generator makes. Every key is required: there are no
+    defaults."""
+    a = mix.get("arrival")
+    if a is None:
+        return
+
+    def number(key, least, strict):
+        v = a[key]
+        if isinstance(v, bool) or not isinstance(v, (int, float)) \
+                or v < least or (strict and v == least):
+            raise ValueError(f"arrival {key} {v!r}")
+
+    missing = [k for k in ARRIVAL_KEYS if k not in a]
+    if missing:
+        raise ValueError(f"arrival lacks {missing}")
+    if a["kind"] != "open":
+        raise ValueError(f"arrival kind {a['kind']!r}: only 'open' is known")
+    number("rate_msgs_per_s", 0, True)
+    number("warmup_s", 0, False)
+    if (a["on_s"] is None) != (a["off_s"] is None):
+        raise ValueError("arrival on_s and off_s: both or neither null")
+    if a["on_s"] is not None:
+        number("on_s", 0, True)
+        number("off_s", 0, False)
+
+
 def cell(name: str, bench: Optional[dict] = None) -> Cell:
     """The cell called ``name`` with its configuration, mix and metrics.
-    Raises ``KeyError`` for a cell ``BENCHMARK.json`` does not have."""
+    Raises ``KeyError`` for a cell ``BENCHMARK.json`` does not have, and
+    ``ValueError`` for a mix whose ``arrival`` the generator does not
+    make."""
     bench = bench if bench is not None else load_json(ROOT / "BENCHMARK.json")
     entry = {w["name"]: w for w in bench["workloads"]}[name]
     cfg = {c["name"]: c for c in bench["configs"]}[entry["config"]]
+    mix = load_json(HERE / "mixes" / f"{entry['traffic']}.json")
+    check_arrival(mix)
     return Cell(
         name=name,
         config=load_json(ROOT / cfg["file"]),
-        mix=load_json(HERE / "mixes" / f"{entry['traffic']}.json"),
+        mix=mix,
         chips=int(entry["chips"]),
         end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in bench["per_layer"] if _applies(m, name)])

@@ -2,7 +2,8 @@
 refuses to run without a TPU, a sound run is correct, a run whose timed
 path is broken underneath (a step that changes nothing, half of each round
 left out, a token altered where the fused round produces it) is not, and a
-run whose rounds leave the fused device round prints no result."""
+run whose rounds leave the fused device round, or whose window compiles,
+prints no result."""
 import json
 import os
 import shutil
@@ -163,6 +164,41 @@ def test_round_off_the_device_prints_no_result(kind, tmp_path, monkeypatch,
     out, err = capsys.readouterr()
     assert out == ""
     assert "left the fused device round" in err
+
+
+@pytest.mark.parametrize("kind", sorted(CELLS))
+def test_window_that_compiles_prints_no_result(kind, tmp_path, monkeypatch,
+                                               capsys):
+    """A program compiled inside the window times the compiler, not the
+    cell's path: the run is correct but exits non-zero with no result
+    line, and says how many compiles it saw."""
+    import jax
+
+    real_window = ClosedLoop.window
+
+    def window(self, seconds):
+        jax.jit(lambda x: x * 3 + 1)(np.arange(7)).block_until_ready()
+        return real_window(self, seconds)
+
+    monkeypatch.setattr(ClosedLoop, "window", window)
+    line = _run(kind, tmp_path)
+    assert line["correct"] is True
+    n = line["path"]["compiles_in_window"]
+    assert n >= 1
+    capsys.readouterr()
+    assert harness.finish(line) == 4
+    out, err = capsys.readouterr()
+    assert out == ""
+    assert f"{n} compiles in the window" in err
+
+
+def test_off_path_names_the_compile_count():
+    path = {"steps": 5, "fused_rounds": 5, "device_fallbacks": 0,
+            "compiles_in_window": 0}
+    assert harness.off_path(path) is None
+    assert harness.off_path(dict(path, compiles_in_window=3)) == \
+        "3 compiles in the window"
+    assert "fell back" in harness.off_path(dict(path, device_fallbacks=2))
 
 
 def test_window_closes_when_the_requests_run_out(tmp_path, capsys):
